@@ -371,7 +371,7 @@ class FlexagonPlan:
     shapes: Tuple[int, int, int]         # (m, k, n)
     block_shape: Tuple[int, int, int]
     backend: str                         # registry name
-    interpret: Optional[bool]            # None → REPRO_INTERPRET default
+    interpret: Optional[bool]            # None → follow the platform
 
     # -- pytree plumbing -------------------------------------------------
     def tree_flatten(self):
@@ -519,7 +519,7 @@ def flexagon_plan(a_spec: OperandSpec, b_spec: OperandSpec, *,
     ``"simulator"``, ``"autotune"``, or a ``SelectionPolicy``).  An explicit
     ``dataflow=`` pins the choice and bypasses the policy.  ``use_pallas``
     is the seed API's boolean backend switch, honoured when ``backend`` is
-    not given; ``interpret=None`` defers to ``REPRO_INTERPRET``.
+    not given; ``interpret=None`` interprets kernels only on the CPU.
 
     ``memory_budget`` (a :class:`repro.memory.MemoryBudget`) bounds the
     on-chip working set: a pattern that exceeds it is partitioned by the

@@ -18,8 +18,8 @@ All Pallas dispatch lives here (kernels are imported nowhere else outside
   extents so stacked sub-plans scan (``scan_streaming``) and shard
   (``collective_merge``) with traced schedule leaves;
 - interpret mode resolves in exactly one place: an explicit per-plan
-  ``interpret=`` wins, then the backend instance's setting, then the global
-  ``REPRO_INTERPRET`` knob (:mod:`repro.config`).  Compiled (non-interpret)
+  ``interpret=`` wins, then the backend instance's setting, then the
+  platform (:mod:`repro.config`: CPU → interpret).  Compiled (non-interpret)
   execution additionally wants MXU-aligned blocks —
   :meth:`PallasBackend.alignment_diagnostic` surfaces the Mosaic tiling
   rule as a typed ``verify_plan`` diagnostic instead of a compile crash.

@@ -2,12 +2,17 @@
 
 One place for defaults that used to be scattered per-function keywords.
 
-``REPRO_INTERPRET`` — Pallas interpret-mode default for every kernel entry
-point (``ip_spmm``/``op_spmm``/``gust_spmm``/``moe_gmm.gmm``) and for plans
-executed through the ``pallas`` backend.  Unset, kernels run in interpret
-mode (CPU-safe validation, the development default); set ``REPRO_INTERPRET=0``
-on a real TPU to compile natively.  An explicit ``interpret=`` argument at any
-call site still wins.
+Pallas interpret mode — the default for every kernel entry point
+(``stream_spmm``/``stream_panel_spmm``, the ``ip/op/gust_spmm`` wrappers,
+``moe_gmm.gmm``) and for plans executed through the ``pallas`` backend
+follows the platform: kernels are interpreted only when JAX's default
+backend is the CPU, and compile natively everywhere else.  An explicit
+``interpret=`` argument at any call site still wins.
+
+``use_compile_cache`` — JAX's persistent compilation cache for the
+launchers and ``chip_smoke.py``.  ``JAX_COMPILATION_CACHE_DIR``, when set,
+is read by JAX itself and left alone; otherwise the cache lives at the
+fixed in-checkout path :data:`COMPILE_CACHE_DIR` (ignored by git).
 
 ``REPRO_VERIFY`` — pre-execution plan verification default (see
 ``repro.analysis.verify_plan``).  Unset or falsy, plans are handed out
@@ -26,9 +31,15 @@ instead of hand-writing ``XLA_FLAGS``.
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
-__all__ = ["interpret_default", "resolve_interpret", "verify_default",
-           "resolve_verify", "virtual_devices"]
+__all__ = ["COMPILE_CACHE_DIR", "interpret_default", "resolve_interpret",
+           "use_compile_cache", "verify_default", "resolve_verify",
+           "virtual_devices"]
+
+#: fixed persistent-compile-cache path inside the checkout: the path is part
+#: of the cache key, so it never derives from a temp name, pid or the time
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
@@ -54,32 +65,41 @@ def virtual_devices(n: int = 8, *, override: bool = False) -> str:
     return os.environ["XLA_FLAGS"]
 
 _TRUE = {"1", "true", "yes", "on"}
-_FALSE = {"0", "false", "no", "off"}
 
 
 def interpret_default() -> bool:
-    """Global Pallas interpret-mode default (``REPRO_INTERPRET``).
+    """Pallas interpret-mode default: on exactly when the default backend
+    is the CPU (read at call time, so it sees the backend jax settled on)."""
+    import jax
 
-    Read at call time, not import time, so tests and launchers can flip the
-    environment without reloading modules.
-    """
-    raw = os.environ.get("REPRO_INTERPRET", "").strip().lower()
-    if raw in _TRUE:
-        return True
-    if raw in _FALSE:
-        return False
-    return True
+    return jax.default_backend() == "cpu"
 
 
 def resolve_interpret(explicit: bool | None = None) -> bool:
-    """An explicit per-call value wins; ``None`` defers to the global knob."""
+    """An explicit per-call value wins; ``None`` follows the platform."""
     return interpret_default() if explicit is None else bool(explicit)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and this
+    sets nothing.  Otherwise the cache goes to :data:`COMPILE_CACHE_DIR`.
+    Called by the launchers and ``chip_smoke.py``, never by the tests.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def verify_default() -> bool:
     """Global plan-verification default (``REPRO_VERIFY``).
 
-    Read at call time, not import time, like :func:`interpret_default`.
+    Read at call time, not import time, so tests can set it late.
     Off unless explicitly enabled — verification is a debugging/CI gate,
     not a production tax.
     """

@@ -10,7 +10,7 @@ placement), and ``ShardedPlan.apply`` runs them all:
 - on a **collective-merge capable** backend (``ExecutionBackend
   .collective_merge``: ``execute`` accepts traced plan leaves), the
   per-shard plans are padded to one uniform pytree shape, stacked leaf-wise,
-  and executed inside a single ``jax.experimental.shard_map`` — each device
+  and executed inside a single ``jax.shard_map`` — each device
   slices out its own plan, runs the unchanged ``ExecutionBackend.execute``,
   and OP k-slab partitions merge their partial sums with one
   ``jax.lax.psum`` (the MRN's merge phase lifted to the interconnect — the
@@ -18,8 +18,9 @@ placement), and ``ShardedPlan.apply`` runs them all:
 - otherwise (a backend without ``collective_merge`` — both ``reference``
   and ``pallas`` declare it; the pallas kernels consume shape-uniform
   ``StreamSchedule`` work lists, so stacked shard members trace cleanly)
-  the shards unroll into a sequential loop with the same combine —
-  numerically identical, still jit-compatible.
+  the shards unroll into a sequential loop on one device with the same
+  combine — numerically identical, still jit-compatible.
+  :attr:`ShardedPlan.runs_sharded` says which path an apply takes.
 
 The containment hierarchy stays clean: ``ShardedPlan → TiledPlan →
 FlexagonPlan``, every level exposing the same ``apply`` surface.
@@ -148,6 +149,15 @@ class ShardedPlan:
         return tuple(np.asarray(self.mesh.devices).shape)
 
     @property
+    def runs_sharded(self) -> bool:
+        """Does :meth:`apply` take the ``shard_map`` path across the mesh
+        (``False``: every shard runs in sequence on one device)?"""
+        return (self.shard_ok and self.n_shards > 1
+                and getattr(get_backend(self.backend), "collective_merge",
+                            False)
+                and mesh_device_count(self.mesh) >= self.n_shards)
+
+    @property
     def dist_stats(self) -> dict:
         """Shard/collective telemetry (surfaced by ``ServeEngine.stats``)."""
         return {"mesh_shape": self.mesh_shape, "shards": self.n_shards,
@@ -245,10 +255,7 @@ class ShardedPlan:
                             (0, kp * bk - a_d.shape[1])))
         b_d = jnp.pad(b_d, ((0, kp * bk - b_d.shape[0]),
                             (0, np_ * bn - b_d.shape[1])))
-        backend = get_backend(self.backend)
-        if (self.shard_ok and self.n_shards > 1
-                and getattr(backend, "collective_merge", False)
-                and mesh_device_count(self.mesh) >= self.n_shards):
+        if self.runs_sharded:
             out = self._apply_shard_map(a_d, b_d)
         else:
             out = self._apply_serial(a_d, b_d)
@@ -286,8 +293,6 @@ class ShardedPlan:
         sharded-stacked form, each device slices out its own sub-plan and
         runs the backend's unchanged ``execute``; k-slab partitions merge
         partial sums with ``psum`` (the top tier of the merge hierarchy)."""
-        from jax.experimental.shard_map import shard_map
-
         P = jax.sharding.PartitionSpec
         a_spec, b_spec, out_spec = {
             "m": (P("shards", None), P(None, None), P("shards", None)),
@@ -306,9 +311,9 @@ class ShardedPlan:
                 out = jax.lax.psum(out, "shards")
             return out
 
-        fn = shard_map(body, mesh=self._flat_mesh(),
-                       in_specs=(P("shards"), a_spec, b_spec),
-                       out_specs=out_spec, check_rep=False)
+        fn = jax.shard_map(body, mesh=self._flat_mesh(),
+                           in_specs=(P("shards"), a_spec, b_spec),
+                           out_specs=out_spec, check_vma=False)
         return fn(stacked, a_d, b_d)
 
 

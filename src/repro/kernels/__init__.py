@@ -6,7 +6,7 @@
   interpret mode.  Plan-level dispatch lives in
   :mod:`repro.backends.pallas` (the ``pallas`` execution backend), which
   builds the phase-1 schedules once per pattern; interpret-mode defaults
-  resolve through :mod:`repro.config` (``REPRO_INTERPRET``).
+  follow the platform through :mod:`repro.config` (CPU → interpret).
 - ``moe_gmm.gmm`` — grouped matmul (Gustavson-as-deployed for MoE).
 - ``ops.flexagon_spmm`` — deprecated one-shot shim (warns); the plan-once
   entry point is :func:`repro.api.flexagon_plan`.

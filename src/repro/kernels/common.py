@@ -8,9 +8,10 @@ streams, and the same MXU block-GEMM inner op.  The three dataflow kernels
 differ only in their grid/BlockSpec schedules — reduction and merging are two
 configurations of this substrate, not two hardware stacks.
 
-Everything here runs in ``interpret=True`` mode on CPU for validation; on a
-real TPU the same code compiles natively (BlockSpecs are MXU-aligned when the
-caller uses 128-multiple blocks).
+On the CPU the kernels run in Pallas interpret mode for validation; on a TPU
+the same code compiles natively (BlockSpecs are MXU-aligned when the caller
+uses 128-multiple blocks).  ``repro.config.resolve_interpret`` picks the
+mode from the platform.
 """
 from __future__ import annotations
 
@@ -33,19 +34,10 @@ __all__ = [
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bk, bn) — MXU-aligned
 
 
-def compiler_params(dimension_semantics: tuple[str, ...] | None = None):
-    """TPU compiler params; harmless under interpret mode."""
-    if dimension_semantics is None:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=dimension_semantics)
-    except TypeError:
-        return None
+def compiler_params(dimension_semantics: tuple[str, ...]):
+    """TPU compiler params (grid dimension semantics); ignored when the
+    kernel is interpreted."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def grid_spec(num_scalar_prefetch: int, grid, in_specs, out_specs,

@@ -41,7 +41,7 @@ def gust_spmm(a: BlockCSR, b: BlockCSR, plan: StreamPlan | None = None, *,
     ``schedule`` (from :func:`repro.kernels.stream.schedule_from_stream`
     with ``by_dest=False``) carries the phase-1 i-major work list;
     omitted, it is rebuilt host-side from the operand structure.
-    ``interpret=None`` defers to the global knob (``REPRO_INTERPRET``).
+    ``interpret=None`` follows the platform (CPU → interpret).
     """
     interpret = resolve_interpret(interpret)
     if a.nnzb == 0 or b.nnzb == 0:

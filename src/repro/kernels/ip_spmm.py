@@ -36,7 +36,7 @@ def ip_spmm(a: BlockCSR, b: BlockCSC, plan: IPPlan | None = None, *,
     ``schedule`` (from :func:`repro.kernels.stream.schedule_from_ip`)
     carries the phase-1 work list; omitted, it is rebuilt host-side from
     ``plan`` (which is itself rebuilt from the operand structure when
-    omitted).  ``interpret=None`` defers to ``REPRO_INTERPRET``.
+    omitted).  ``interpret=None`` follows the platform (CPU → interpret).
     """
     interpret = resolve_interpret(interpret)
     if a.nnzb == 0 or b.nnzb == 0:

@@ -44,7 +44,8 @@ def gmm(x: jax.Array, w: jax.Array, group_ids: jax.Array, *,
     """Grouped matmul: out[t*bm:(t+1)*bm] = x[t*bm:(t+1)*bm] @ w[group_ids[t]].
 
     Requires M % bm == K % bk == N % bn == 0 (callers pad; see
-    :func:`pad_groups`).  ``interpret=None`` defers to ``REPRO_INTERPRET``.
+    :func:`pad_groups`).  ``interpret=None`` follows the platform
+    (CPU → interpret).
     """
     interpret = resolve_interpret(interpret)
     m, kdim = x.shape

@@ -35,8 +35,8 @@ def op_spmm(a: BlockCSC, b: BlockCSR, plan: StreamPlan | None = None, *,
 
     ``schedule`` (from :func:`repro.kernels.stream.schedule_from_stream`
     with ``by_dest=True``) carries the destination-sorted phase-1 work
-    list; omitted, it is rebuilt host-side.  ``interpret=None`` defers to
-    the global knob (``REPRO_INTERPRET``).
+    list; omitted, it is rebuilt host-side.  ``interpret=None`` follows
+    the platform (CPU → interpret).
     """
     interpret = resolve_interpret(interpret)
     if a.nnzb == 0 or b.nnzb == 0:

@@ -87,7 +87,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     cell = build_cell(arch, shape_name, mesh, microbatches=microbatches,
                       variant=variant)
     donate = cell.static_desc.get("donate", ())
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                           donate_argnums=donate).lower(*cell.args)
         compiled = lowered.compile()

@@ -3,12 +3,17 @@
 Functions (not module-level constants) so importing this module never
 touches jax device state — launchers must set XLA_FLAGS (via
 :func:`repro.config.virtual_devices`) before jax's first backend init.
+
+Meshes use ``Auto`` axis types: the models place parameters with
+``NamedSharding`` and let the compiler propagate activations, which
+``Explicit`` axes (``jax.make_mesh``'s default) would refuse for the
+embedding gather.  Enter them with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_virtual_mesh"]
 
@@ -18,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
@@ -30,7 +35,8 @@ def make_local_mesh():
     device count.
     """
     n = max(1, len(jax.devices()))
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_virtual_mesh(n: int = 8, axis_name: str = "shards") -> Mesh:

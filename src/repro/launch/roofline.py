@@ -176,7 +176,7 @@ def _probe_cost(cfg, shape, mesh, tcfg_over=None) -> dict:
                 shardings = (params_sharding(params, mesh, cfg),
                              cache_sharding(cache, mesh, cfg),
                              batch_sharding(tok, mesh))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=shardings).lower(*args)
             compiled = lowered.compile()
     cost = compiled.cost_analysis()

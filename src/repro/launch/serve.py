@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke \
         --requests 8 --slots 4 --max-new 16
+
+``main`` returns the engine and its requests (prompts and generated
+tokens), so a caller can check the answers against the model.
 """
 from __future__ import annotations
 
@@ -11,6 +14,7 @@ import jax
 import numpy as np
 
 from .. import obs
+from ..config import use_compile_cache
 from ..configs import get_config
 from ..models import build_model
 from ..serve.engine import Request, ServeEngine
@@ -26,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -35,10 +40,12 @@ def main(argv=None):
 
     rng = np.random.default_rng(args.seed)
     t0 = obs.now_ns()    # the obs monotonic clock (repro-wide telemetry)
+    requests = []
     for rid in range(args.requests):
         prompt = rng.integers(0, cfg.vocab,
                               size=int(rng.integers(4, 17))).astype(np.int64)
-        engine.submit(Request(rid, prompt, max_new_tokens=args.max_new))
+        requests.append(Request(rid, prompt, max_new_tokens=args.max_new))
+        engine.submit(requests[-1])
     results = engine.run_to_completion()
     dt = (obs.now_ns() - t0) / 1e9
     total_new = sum(len(v) for v in results.values())
@@ -53,7 +60,7 @@ def main(argv=None):
         print(f"[serve] decode_step p50 {dec.get('p50', 0) * 1e3:.1f} ms "
               f"p99 {dec.get('p99', 0) * 1e3:.1f} ms "
               f"over {dec.get('count', 0)} steps")
-    return results
+    return engine, requests
 
 
 if __name__ == "__main__":

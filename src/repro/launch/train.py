@@ -20,6 +20,7 @@ import numpy as np
 
 from .mesh import make_local_mesh, make_production_mesh
 from ..checkpoint.checkpointer import Checkpointer
+from ..config import use_compile_cache
 from ..configs import get_config
 from ..configs.base import TrainConfig
 from ..data.pipeline import make_batch_iterator
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     tcfg = TrainConfig(global_batch=args.batch, seq_len=args.seq, lr=args.lr,
@@ -57,7 +59,7 @@ def main(argv=None):
     mesh = (make_production_mesh() if args.production_mesh
             else make_local_mesh())
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = init_train_state(model, jax.random.PRNGKey(tcfg.seed), tcfg)
         p_shard = params_sharding(state.params, mesh, cfg)
         state = state._replace(

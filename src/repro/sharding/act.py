@@ -1,9 +1,9 @@
 """Activation sharding constraints.
 
-``shard(x, *axes)`` applies ``with_sharding_constraint`` when the enclosing
-mesh defines the named axes, and is a no-op otherwise — model code stays
-runnable on a bare CPU (smoke tests) and correctly constrained under the
-production mesh (dry-run / training).
+``shard(x, *axes)`` applies ``with_sharding_constraint`` when a mesh set by
+``jax.set_mesh`` defines the named axes, and is a no-op otherwise — model
+code stays runnable on a bare CPU (smoke tests) and correctly constrained
+under the production mesh (dry-run / training).
 
 Convention: ``"dp"`` expands to the data-parallel axes ("pod","data") that
 exist on the current mesh.
@@ -19,22 +19,10 @@ __all__ = ["shard", "dp_axes"]
 
 
 def _current_axis_names():
-    # the `with mesh:` context manager (used around every production
-    # lowering) registers the physical mesh on thread_resources
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if not mesh.empty:
-            return tuple(mesh.axis_names)
-    except Exception:       # noqa: BLE001
-        pass
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return tuple(mesh.axis_names)
-    except Exception:       # noqa: BLE001
-        pass
-    return ()
+    # `with jax.set_mesh(mesh):` (used around every sharded lowering)
+    # installs the mesh this reads
+    mesh = jax.sharding.get_abstract_mesh()
+    return () if mesh.empty else tuple(mesh.axis_names)
 
 
 def dp_axes():
@@ -59,7 +47,4 @@ def shard(x, *axes):
             spec.append(kept if kept else None)
         else:
             spec.append(a if a in names else None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:       # noqa: BLE001 — e.g. no mesh context
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

@@ -12,7 +12,8 @@ Contracts under test (DESIGN.md §11):
   measures once per fingerprint); Fixed pins;
 - phase-1-once: ``plan.apply`` on the pallas backend leaves
   ``PHASE1_COUNTERS`` untouched;
-- the interpret knob centralizes in ``repro.config`` / ``REPRO_INTERPRET``;
+- the interpret default centralizes in ``repro.config`` and follows the
+  platform (CPU → interpret); an explicit argument wins;
 - ``flexagon_spmm`` emits a real ``DeprecationWarning``.
 """
 import jax
@@ -240,16 +241,21 @@ def test_simulator_backend_cost_and_report():
 
 
 def test_interpret_knob_centralized(monkeypatch):
-    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+    from repro.backends import PallasBackend
+
+    plan = flexagon_plan(*_case(), block_shape=BS, backend="pallas")
+    # the default follows the platform: the CPU interprets ...
+    assert jax.default_backend() == "cpu"
     assert interpret_default() is True
     assert resolve_interpret(None) is True
     assert resolve_interpret(False) is False
-    monkeypatch.setenv("REPRO_INTERPRET", "0")
+    # ... any accelerator compiles natively; an explicit argument still wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert interpret_default() is False
     assert resolve_interpret(None) is False
     assert resolve_interpret(True) is True
-    monkeypatch.setenv("REPRO_INTERPRET", "on")
-    assert interpret_default() is True
+    assert PallasBackend()._interpret(plan) is False
+    assert PallasBackend(interpret=True)._interpret(plan) is True
 
 
 def test_flexagon_spmm_warns_deprecated():

@@ -159,7 +159,7 @@ def test_budget_forces_one_two_many_tiles(budget, lo, hi):
     np.testing.assert_allclose(out, a @ b, rtol=1e-3, atol=1e-3)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.sampled_from(df.DATAFLOWS),
        st.floats(min_value=0.15, max_value=0.9),
        st.floats(min_value=0.15, max_value=0.9),

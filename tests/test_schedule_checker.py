@@ -98,7 +98,7 @@ def test_verify_cache_catches_retargeted_readmission():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 10_000),
        m=st.sampled_from((32, 48, 64)),
        k=st.sampled_from((32, 48, 64)),
@@ -118,7 +118,7 @@ def test_checker_accepts_all_dataflows(seed, m, k, n, da, db):
         assert not errors_of(diags), (dataflow, [str(d) for d in diags])
 
 
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(seed=st.integers(0, 10_000), da=st.floats(0.15, 0.5),
        db=st.floats(0.15, 0.5))
 def test_checker_accepts_sharded_stacks(seed, da, db):

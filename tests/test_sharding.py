@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs import ARCH_IDS, get_config
 from repro.models import build_model
@@ -17,7 +17,8 @@ def mesh():
     devs = np.asarray(jax.devices())
     if devs.size < 2:
         pytest.skip("needs >1 local device")
-    return jax.make_mesh((devs.size // 2, 2), ("data", "model"))
+    return jax.make_mesh((devs.size // 2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _check_divisible(tree_struct, shardings, mesh):
@@ -72,7 +73,7 @@ def test_sharded_forward_matches_single_device(mesh):
                      .astype(jnp.float32))
     shardings = params_sharding(params, mesh, cfg)
     sharded = jax.tree.map(jax.device_put, params, shardings)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, t: model.logits(p, t, remat=False))(
             sharded, tok)
     err = np.abs(np.asarray(out.astype(jnp.float32)) - ref).max()

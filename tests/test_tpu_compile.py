@@ -1,0 +1,107 @@
+"""Compile rehearsal: the streaming SpMSpM kernels at qwen2-1.5b FFN width,
+compiled for a described (not attached) TPU v5e.
+
+Nothing runs here: each test lowers a kernel with ``interpret=False`` for
+one chip of a ``v5e:2x2`` topology and compiles it with the TPU compiler,
+which refuses misaligned slices, over-budget VMEM and unpartitionable
+kernels before any chip time is spent.  The platform check in
+``repro.config`` sees the CPU in this process, so ``interpret=False`` is
+passed explicitly.
+
+The topology is described inside a module fixture (never at import or
+collection time): only one process may load the TPU library, and every
+test worker imports this file.
+"""
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro import flexagon_plan
+from repro.kernels.stream import _stream_panel_spmm, _stream_spmm
+
+D_MODEL, D_FF = 1536, 8960          # qwen2-1.5b (src/repro/configs)
+BLOCK = 128
+SPARSITY = 0.75
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:      # noqa: BLE001 — any failure: skip
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one — keep the cache off here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def w_gate():
+    """A 75%-block-sparse (d_model, d_ff) weight with 128-aligned blocks."""
+    rng = np.random.default_rng(0)
+    mask = rng.random((D_MODEL // BLOCK, D_FF // BLOCK)) >= SPARSITY
+    full = np.repeat(np.repeat(mask, BLOCK, 0), BLOCK, 1)
+    return (rng.standard_normal((D_MODEL, D_FF)) * full).astype(np.float32)
+
+
+def _kernel_args(plan, sharding):
+    """Shapes of what ``PallasBackend.execute`` hands the kernel, on
+    ``sharding``: N-stationary plans run the transposed problem."""
+    m, _, n = plan.shapes
+    a_nnzb, b_nnzb = plan.a_layout.nnzb, plan.b_layout.nnzb
+    if plan.dataflow.endswith("_n"):
+        a_nnzb, b_nnzb, m, n = b_nnzb, a_nnzb, n, m
+    blk = (BLOCK, BLOCK)
+    a = jax.ShapeDtypeStruct((a_nnzb, *blk), jnp.float32, sharding=sharding)
+    b = jax.ShapeDtypeStruct((b_nnzb, *blk), jnp.float32, sharding=sharding)
+    sched = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.int32,
+                                       sharding=sharding),
+        plan.aux["stream_schedule"])
+    grid = (-(-m // BLOCK), -(-n // BLOCK))
+    return a, b, sched, grid, (m, n)
+
+
+@pytest.mark.parametrize("tokens", [4, 512])
+@pytest.mark.parametrize("dataflow,kernel", [
+    ("ip_m", _stream_spmm),
+    ("op_m", _stream_spmm),
+    ("gust_m", _stream_panel_spmm),
+    ("op_n", _stream_spmm),
+])
+def test_stream_kernel_compiles_for_v5e(one_chip, w_gate, dataflow, kernel,
+                                        tokens):
+    plan = flexagon_plan((tokens, D_MODEL), w_gate, dataflow=dataflow,
+                         block_shape=(BLOCK,) * 3, backend="pallas",
+                         verify=False)
+    assert "dense" not in plan.aux      # 75% sparse: the kernel path
+    a, b, sched, grid, shape = _kernel_args(plan, one_chip)
+    compiled = kernel.lower(a, b, sched, out_grid=grid, out_shape=shape,
+                            out_dtype=jnp.float32,
+                            interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis() is not None
