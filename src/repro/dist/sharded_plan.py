@@ -236,14 +236,10 @@ class ShardedPlan:
 
     def apply(self, a, b, out_dtype=jnp.float32) -> jax.Array:
         """Execute C = A @ B across the shards.  jit-compatible, zero host
-        work; collective-capable backends run one ``shard_map``."""
-        if obs.enabled():
-            with obs.span("dist.sharded.apply", dataflow=self.dataflow,
-                          shards=self.n_shards, axis=self.axis,
-                          collective=self.collective,
-                          ici_bytes=float(self.ici_bytes)):
-                return self._apply_inner(a, b, out_dtype)
-        return self._apply_inner(a, b, out_dtype)
+        work; collective-capable backends run one ``shard_map``.  The
+        device work is named ``dist.sharded.apply`` in a trace."""
+        with jax.named_scope("dist.sharded.apply"):
+            return self._apply_inner(a, b, out_dtype)
 
     def _apply_inner(self, a, b, out_dtype=jnp.float32) -> jax.Array:
         m, k, n = self.shapes

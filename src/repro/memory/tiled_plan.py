@@ -432,37 +432,11 @@ class TiledPlan:
             return x.todense()
         return jnp.asarray(x)
 
-    def _traffic_attrs(self) -> Dict[str, Any]:
-        """Tier-traffic span attributes, computed once per plan.
-
-        Only evaluated when tracing is on (the estimator is host work) and
-        memoized on the plan object so repeated traced applies pay a single
-        estimation.
-        """
-        cached = getattr(self, "_tier_attrs_cache", None)
-        if cached is None:
-            try:
-                from .traffic import plan_traffic
-
-                t = plan_traffic(self).traffic  # lint: host-ok (trace-gated)
-                cached = {"l1_bytes": t.l1_bytes, "l2_bytes": t.l2_bytes,
-                          "dram_bytes": t.dram_bytes,
-                          "merge_bytes": t.merge_bytes}
-                reg = obs.get_registry()
-                for tier in ("l1", "l2", "dram"):
-                    reg.gauge(f"tier.{tier}_bytes").set(cached[f"{tier}_bytes"])
-            except Exception:      # pricing must never break execution
-                cached = {}
-            object.__setattr__(self, "_tier_attrs_cache", cached)
-        return cached
-
     def apply(self, a, b, out_dtype=jnp.float32) -> jax.Array:
-        """Execute C = A @ B tile by tile.  jit-compatible, zero host work."""
-        if obs.enabled():
-            with obs.span("memory.tiled.apply", dataflow=self.dataflow,
-                          tiles=self.n_tiles, **self._traffic_attrs()):
-                return self._apply_inner(a, b, out_dtype)
-        return self._apply_inner(a, b, out_dtype)
+        """Execute C = A @ B tile by tile.  jit-compatible, zero host work;
+        the device work is named ``memory.tiled.apply`` in a trace."""
+        with jax.named_scope("memory.tiled.apply"):
+            return self._apply_inner(a, b, out_dtype)
 
     def _apply_inner(self, a, b, out_dtype=jnp.float32) -> jax.Array:
         m, k, n = self.shapes

@@ -12,6 +12,15 @@ module runs phase 1 *once* per (token count, layer) through the plan API:
   shape-specialized plan from the per-FFN cache (`CompressedFFN.specialize`),
   built at admission and reused every subsequent step.
 
+Observability (:mod:`repro.obs`): set-up runs under an ``ffn.compress``
+span with ``ffn.mask``, ``plan.phase1`` and ``ffn.pack`` children, and
+counts ``ffn.mask_s`` / ``ffn.pack_s`` (and, through the plan API,
+``plan.build_s``) into the global registry; ``ffn.block_pairs`` holds the
+block pairs one call of the last planned shape multiplies.  At run time
+the matmuls run under the ``jax.named_scope`` names ``ffn.gate`` /
+``ffn.up`` / ``ffn.down`` and the SiLU·mul under ``ffn.act``, which name
+their device work in a profiler trace and cost nothing per call.
+
 The activations-side operand is dense here (weights sparse × activations
 dense), the SpMM special case of SpMSpM — `flexagon_plan` takes the bare
 ``(tokens, d)`` shape as a fully-dense pattern.
@@ -26,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..api import FlexagonPlan, PlanCache, SparseOperand, flexagon_plan
 from ..core.selector import TPUSpec
 from .ffn import _masked_weight
@@ -42,6 +52,21 @@ class PlannedFFN:
     w_gate: SparseOperand
     w_up: SparseOperand
     w_down: SparseOperand
+
+    def block_pairs(self) -> Dict[str, Optional[int]]:
+        """Effectual (weight, activation) block pairs one call multiplies,
+        per matmul: each plan's stream-schedule work entries that are real.
+        ``None`` for a plan without a stream schedule (reference backend,
+        tiled or sharded plans)."""
+        return {"gate": _block_pairs(self.plan_in),
+                "up": _block_pairs(self.plan_in),
+                "down": _block_pairs(self.plan_out)}
+
+
+def _block_pairs(plan) -> Optional[int]:
+    aux = getattr(plan, "aux", None)
+    sched = aux.get("stream_schedule") if isinstance(aux, dict) else None
+    return None if sched is None else sched.n_real_work
 
 
 class CompressedFFN:
@@ -104,7 +129,11 @@ class CompressedFFN:
         key = (which, plan.formats[1])
         packed = self._packed.get(key)
         if packed is None:
-            packed = plan.pack_b(w)
+            t0 = obs.now_ns()
+            with obs.span("ffn.pack", which=which):
+                packed = jax.block_until_ready(plan.pack_b(w))
+            obs.get_registry().histogram("ffn.pack_s").observe(
+                (obs.now_ns() - t0) / 1e9)
             self._packed[key] = packed
         return packed
 
@@ -136,6 +165,9 @@ class CompressedFFN:
                            self._pack("gate", wg, plan_in),
                            self._pack("up", wu, plan_in),
                            self._pack("down", wd, plan_out))
+        pairs = entry.block_pairs().values()
+        if None not in pairs:
+            obs.get_registry().gauge("ffn.block_pairs").set(sum(pairs))
         self._by_tokens[tokens] = entry
         self.plan_builds += 1
         if self.max_shapes is not None \
@@ -192,17 +224,21 @@ def compress_ffn(ffn_params: Dict[str, Any], *, tokens: int,
     ``repro.analysis.verify_plan`` (``None`` defers to ``REPRO_VERIFY``).
     """
     assert "block_mask" in ffn_params, "FFN is not block-pruned"
-    wg = np.asarray(_masked_weight(ffn_params["w_gate"]["w"],
-                                   ffn_params["block_mask"]))
-    wu = np.asarray(_masked_weight(ffn_params["w_up"]["w"],
-                                   ffn_params["block_mask"]))
-    wd = np.asarray(_masked_weight(ffn_params["w_down"]["w"],
-                                   ffn_params["block_mask"].T))
-    return CompressedFFN(wg, wu, wd, tokens=tokens, block=block, spec=spec,
-                         backend=backend, policy=policy,
-                         memory_budget=memory_budget, mesh=mesh,
-                         partition=partition, plan_cache=plan_cache,
-                         max_shapes=max_shapes, verify=verify)
+    mask = ffn_params["block_mask"]
+    with obs.span("ffn.compress", tokens=tokens):
+        t0 = obs.now_ns()
+        with obs.span("ffn.mask"):
+            wg = np.asarray(_masked_weight(ffn_params["w_gate"]["w"], mask))
+            wu = np.asarray(_masked_weight(ffn_params["w_up"]["w"], mask))
+            wd = np.asarray(_masked_weight(ffn_params["w_down"]["w"],
+                                           mask.T))
+        obs.get_registry().histogram("ffn.mask_s").observe(
+            (obs.now_ns() - t0) / 1e9)
+        return CompressedFFN(wg, wu, wd, tokens=tokens, block=block,
+                             spec=spec, backend=backend, policy=policy,
+                             memory_budget=memory_budget, mesh=mesh,
+                             partition=partition, plan_cache=plan_cache,
+                             max_shapes=max_shapes, verify=verify)
 
 
 def sparse_ffn_apply(comp: CompressedFFN, x: jax.Array) -> jax.Array:
@@ -210,7 +246,16 @@ def sparse_ffn_apply(comp: CompressedFFN, x: jax.Array) -> jax.Array:
     b, s, d = x.shape
     entry = comp.specialize(b * s)          # cache hit on steady-state shapes
     x2d = x.reshape(b * s, d).astype(jnp.float32)
-    g = jax.nn.silu(entry.plan_in.apply(x2d, entry.w_gate))
-    u = entry.plan_in.apply(x2d, entry.w_up)
-    y = entry.plan_out.apply(g * u, entry.w_down)
+    # the scopes name each matmul's device work; the order of the
+    # operations is the scheduler's starting point, so it stays as it was
+    with jax.named_scope("ffn.gate"):
+        g = entry.plan_in.apply(x2d, entry.w_gate)
+    with jax.named_scope("ffn.act"):
+        g = jax.nn.silu(g)
+    with jax.named_scope("ffn.up"):
+        u = entry.plan_in.apply(x2d, entry.w_up)
+    with jax.named_scope("ffn.act"):
+        h = g * u
+    with jax.named_scope("ffn.down"):
+        y = entry.plan_out.apply(h, entry.w_down)
     return y.reshape(b, s, d).astype(x.dtype)

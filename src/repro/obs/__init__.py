@@ -9,8 +9,9 @@ Two halves, one import:
   counters / gauges / histograms replacing the per-subsystem stats dicts.
   On unless ``REPRO_METRICS=0``.
 
-This package imports only the stdlib (jax is touched lazily, for optional
-device annotations), so any repro module can depend on it without cycles.
+This package imports only the stdlib (jax is touched lazily, to mirror
+live spans onto a profiler trace), so any repro module can depend on it
+without cycles.
 
 CLI: ``python -m repro.obs {demo,export,summarize,dump,validate}``.
 """

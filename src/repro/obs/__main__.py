@@ -51,9 +51,8 @@ def _cmd_demo(args) -> int:
     plan = flexagon_plan(ah, bh, dataflow="mixed", block_shape=(8, 8, 8),
                          memory_budget=budget, policy="simulator",
                          backend="simulator")
-    # unjitted on purpose: each apply re-enters Python, so the trace shows
-    # one memory.tiled.apply span per execution (under jit only the single
-    # trace-time span would appear)
+    # applies name their device work with jax.named_scope, not spans: the
+    # trace below holds the plan build's host spans
     for _ in range(args.steps):
         np.asarray(plan.apply(ah, bh))
 

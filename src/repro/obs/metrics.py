@@ -14,18 +14,20 @@ namespace       examples
                 ``policy.measurements``, ``policy.learned_fallbacks``
 ``serve.*``     ``serve.prefills``, ``serve.latency.decode_step_s``
 ``dist.*``      ``dist.ici_bytes``
-``tier.*``      ``tier.l1_bytes``, ``tier.l2_bytes``, ``tier.dram_bytes``
+``ffn.*``       ``ffn.block_pairs`` (gauge), ``ffn.mask_s``, ``ffn.pack_s``
 ==============  =============================================================
 
 Instruments are created on first touch (``registry.counter(name).inc()``)
 and are thread-safe.  ``REPRO_METRICS=0`` turns every instrument into a
 shared no-op so instrumented code needs no branches.
 
-Histograms use fixed log-spaced buckets (4 per decade, spanning 1e-6..1e2
-by default — microseconds to minutes when recording seconds).  Percentiles
-(p50/p90/p99) are read from the cumulative bucket counts, so a reported
-quantile is exact to within one bucket ratio (~1.78x); tests pin this
-against numpy.  ``sum``/``count``/``min``/``max`` are exact.
+Histograms count every observation into fixed log-spaced buckets (4 per
+decade, spanning 1e-6..1e2 by default — microseconds to minutes when
+recording seconds) and keep the last ``SAMPLES`` raw observations in a
+ring.  Percentiles (p50/p90/p99) are nearest-rank over that ring, exactly
+``numpy.percentile(..., method="inverted_cdf")`` of the retained samples
+(tests pin this).  ``sum``/``count``/``min``/``max`` and the bucket counts
+cover every observation.
 
 The process-global registry is :func:`get_registry`; components that need
 isolation (one ``MetricsRegistry`` per ``ServeEngine``) construct their
@@ -37,6 +39,7 @@ import json
 import math
 import os
 import threading
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
@@ -59,6 +62,10 @@ def metrics_enabled() -> bool:
     if raw is None:
         return True
     return raw.strip().lower() not in _FALSE
+
+
+#: raw observations a :class:`Histogram` keeps for its quantiles
+SAMPLES = 4096
 
 
 def default_buckets(lo: float = 1e-6, hi: float = 1e2,
@@ -117,17 +124,19 @@ class Gauge:
 
 
 class Histogram:
-    """Fixed-bucket histogram with quantile estimates.
+    """Fixed-bucket histogram with exact quantiles over recent samples.
 
     ``buckets`` are upper bounds (ascending); observations above the last
-    bound land in a +inf overflow bucket.  Quantiles report the upper bound
-    of the bucket containing the target rank — exact to one bucket ratio.
+    bound land in a +inf overflow bucket.  The last ``samples`` raw
+    observations are kept in a ring, and quantiles are nearest-rank over
+    it.
     """
 
     __slots__ = ("name", "buckets", "_counts", "_sum", "_count",
-                 "_min", "_max", "_lock")
+                 "_min", "_max", "_recent", "_lock")
 
-    def __init__(self, name: str, buckets: Optional[Tuple[float, ...]] = None):
+    def __init__(self, name: str, buckets: Optional[Tuple[float, ...]] = None,
+                 samples: int = SAMPLES):
         self.name = name
         self.buckets = tuple(buckets) if buckets else default_buckets()
         self._counts = [0] * (len(self.buckets) + 1)  # +1 = overflow
@@ -135,6 +144,7 @@ class Histogram:
         self._count = 0
         self._min = math.inf
         self._max = -math.inf
+        self._recent: "deque[float]" = deque(maxlen=samples)
         self._lock = threading.Lock()
 
     def observe(self, value: float) -> None:
@@ -144,6 +154,7 @@ class Histogram:
             self._counts[idx] += 1
             self._sum += value
             self._count += 1
+            self._recent.append(value)
             if value < self._min:
                 self._min = value
             if value > self._max:
@@ -169,20 +180,17 @@ class Histogram:
         return self._sum
 
     def quantile(self, q: float) -> float:
-        """Upper bound of the bucket holding the q-quantile observation."""
+        """Nearest-rank q-quantile of the retained samples: the smallest
+        sample whose empirical CDF reaches ``q`` (numpy's
+        ``inverted_cdf``, with its arithmetic)."""
         with self._lock:
-            total = self._count
-            if total == 0:
-                return 0.0
-            rank = q * total
-            cum = 0
-            for i, c in enumerate(self._counts):
-                cum += c
-                if cum >= rank:
-                    if i < len(self.buckets):
-                        return self.buckets[i]
-                    return self._max  # overflow bucket: best bound we have
-            return self._max
+            vals = sorted(self._recent)
+        if not vals:
+            return 0.0
+        pos = len(vals) * q - 1
+        below = math.floor(pos)
+        rank = below + 1 if pos > below else below
+        return vals[min(max(rank, 0), len(vals) - 1)]
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -194,6 +202,10 @@ class Histogram:
                 "min": self._min if count else 0.0,
                 "max": self._max if count else 0.0,
                 "mean": (self._sum / count) if count else 0.0,
+                # [upper bound, count] of each non-empty bucket; None
+                # bounds the overflow bucket
+                "buckets": [[b, c] for b, c in zip(
+                    self.buckets + (None,), self._counts) if c],
             }
         out["p50"] = self.quantile(0.50)
         out["p90"] = self.quantile(0.90)

@@ -23,9 +23,12 @@ Exports:
 - :func:`summarize` — a human per-span-name latency table (count, total,
   mean, p50, p99, max).
 
-``REPRO_TRACE_DEVICE=1`` additionally wraps every span in a
-``jax.profiler.TraceAnnotation`` so spans show up on the device timeline
-when a real JAX profiler is attached (a no-op otherwise).
+Every live span is also a ``jax.profiler.TraceAnnotation``, so with a
+JAX profiler attached the program's host spans sit on the device trace's
+clock beside the device work (cheap otherwise).  Device work itself is
+named with ``jax.named_scope`` at trace time (``ffn.gate``,
+``memory.tiled.apply``), never with a span: under ``jit`` a span would
+time the tracing, not the execution.
 """
 from __future__ import annotations
 
@@ -88,12 +91,6 @@ def _reset_override() -> None:
     """Return to environment-driven behaviour (test hygiene)."""
     global _OVERRIDE
     _OVERRIDE = None
-
-
-def device_annotations_enabled() -> bool:
-    """``REPRO_TRACE_DEVICE`` — mirror spans onto the JAX device timeline."""
-    raw = os.environ.get("REPRO_TRACE_DEVICE")
-    return raw is not None and raw.strip().lower() in _TRUE
 
 
 class SpanRecord:
@@ -264,7 +261,6 @@ class _Span:
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
-        self._ann = None
 
     def set(self, **attrs: Any) -> None:
         """Attach attributes mid-span (e.g. a result computed inside)."""
@@ -276,10 +272,9 @@ class _Span:
         self.parent = stack[-1] if stack else None
         self.sid = tr.new_id()
         stack.append(self.sid)
-        if device_annotations_enabled():
-            self._ann = _device_annotation(self.name)
-            if self._ann is not None:
-                self._ann.__enter__()
+        self._ann = _device_annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = now_ns()
         return self
 

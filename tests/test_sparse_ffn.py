@@ -62,3 +62,83 @@ def test_plans_built_once_per_token_shape(pruned_ffn):
     sparse_ffn_apply(comp, x2)
     sparse_ffn_apply(comp, x2)
     assert comp.plan_builds == 2 and comp.plan_hits == 4
+
+
+def test_block_pairs_are_kept_blocks_times_m_blocks_for_ip_m(pruned_ffn):
+    """The program's own per-call work count: for IP, every kept weight
+    block meets each of the activations' m-blocks once.  Phase 1 records
+    it, with the schedule's grid steps and runs, on ``plan.prepare``."""
+    from repro import obs
+    from repro.obs import trace as trace_mod
+
+    cfg, params = pruned_ffn
+    tokens = 48                                   # three 16-row m-blocks
+    tracer = obs.get_tracer()
+    tracer.clear()
+    obs.enable()
+    try:
+        comp = compress_ffn(params, tokens=tokens, block=16,
+                            backend="pallas", policy="ip_m")
+        prepare = [s.attrs for s in tracer.spans()
+                   if s.name == "plan.prepare"]
+    finally:
+        trace_mod._reset_override()
+        tracer.clear()
+    entry = comp.specialize(tokens)
+    assert (entry.plan_in.dataflow, entry.plan_out.dataflow) == ("ip_m",
+                                                                 "ip_m")
+    kept = int((np.asarray(params["block_mask"]) > 0).sum())
+    pairs = entry.block_pairs()
+    assert pairs == {"gate": 3 * kept, "up": 3 * kept, "down": 3 * kept}
+    assert obs.get_registry().value("ffn.block_pairs") == 9 * kept
+    assert [a["block_pairs"] for a in prepare] == [3 * kept, 3 * kept]
+    for a, plan in zip(prepare, (entry.plan_in, entry.plan_out)):
+        sched = plan.aux["stream_schedule"]
+        assert (a["grid_steps"], a["runs"]) == (sched.n_work, sched.n_runs)
+
+
+def test_block_pairs_are_none_without_a_stream_schedule(pruned_ffn):
+    cfg, params = pruned_ffn
+    comp = compress_ffn(params, tokens=16, block=16, backend="reference")
+    assert comp.specialize(16).block_pairs() == {"gate": None, "up": None,
+                                                 "down": None}
+
+
+def test_compress_span_tree_and_untraced_counts(pruned_ffn):
+    """``ffn.compress`` roots the set-up spans: the masking, both plans'
+    phase 1 and one pack per weight.  With tracing off the spans are the
+    shared no-op, and the set-up histograms still count."""
+    from repro import obs
+    from repro.obs import trace as trace_mod
+
+    cfg, params = pruned_ffn
+    tracer = obs.get_tracer()
+    reg = obs.get_registry()
+    packs0 = reg.value("ffn.pack_s")
+    obs.disable()
+    try:
+        assert obs.span("ffn.compress") is trace_mod._NOOP
+        tracer.clear()
+        compress_ffn(params, tokens=16, block=16)
+        assert len(tracer) == 0
+        assert reg.value("ffn.pack_s") == packs0 + 3
+        obs.enable()
+        compress_ffn(params, tokens=16, block=16)
+        spans = tracer.spans()
+    finally:
+        trace_mod._reset_override()
+        tracer.clear()
+    by_sid = {s.sid: s for s in spans}
+    (root,) = [s for s in spans if s.parent is None]
+    assert root.name == "ffn.compress"
+    children = [s for s in spans if s.parent == root.sid]
+    assert [s.name for s in children] == ["ffn.mask", "plan.phase1",
+                                          "plan.phase1", "ffn.pack",
+                                          "ffn.pack", "ffn.pack"]
+    assert [s.attrs["which"] for s in children[3:]] == ["gate", "up",
+                                                        "down"]
+    prepare = [s for s in spans if s.name == "plan.prepare"]
+    assert len(prepare) == 2
+    for s in prepare:
+        assert by_sid[s.parent].name == "plan.phase1"
+    assert reg.value("ffn.pack_s") == packs0 + 6

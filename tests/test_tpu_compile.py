@@ -105,3 +105,45 @@ def test_stream_kernel_compiles_for_v5e(one_chip, w_gate, dataflow, kernel,
                             interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+def test_ffn_kernels_carry_their_matmul_scope(one_chip, w_gate):
+    """Each of ``sparse_ffn_apply``'s three kernels keeps its matmul's
+    ``jax.named_scope`` in its ``op_name`` metadata after compilation: the
+    name a profiler trace attributes the kernel's device time by."""
+    import dataclasses
+    import re
+
+    from repro.models.sparse_linear import (PlannedFFN, compress_ffn,
+                                            sparse_ffn_apply)
+
+    tokens = 16
+    mask = np.abs(w_gate).reshape(D_MODEL // BLOCK, BLOCK,
+                                  D_FF // BLOCK, BLOCK).sum((1, 3)) > 0
+    params = {"w_gate": {"w": w_gate}, "w_up": {"w": w_gate},
+              "w_down": {"w": np.ascontiguousarray(w_gate.T)},
+              "block_mask": mask}
+    entry = compress_ffn(params, tokens=tokens, backend="pallas",
+                         block=BLOCK, verify=False).specialize(tokens)
+    plans = [dataclasses.replace(p, interpret=False)
+             for p in (entry.plan_in, entry.plan_out)]
+
+    class Fixed:
+        def __init__(self, weights):
+            self.entry = PlannedFFN(*plans, *weights)
+
+        def specialize(self, n):
+            return self.entry
+
+    weights = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (entry.w_gate, entry.w_up, entry.w_down))
+    x = jax.ShapeDtypeStruct((1, tokens, D_MODEL), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(lambda w, x: sparse_ffn_apply(Fixed(w), x)).lower(
+        weights, x).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    scopes = sorted(re.search(r'op_name="[^"]*?/(ffn\.\w+)/', line).group(1)
+                    for line in kernels)
+    assert scopes == ["ffn.down", "ffn.gate", "ffn.up"]
