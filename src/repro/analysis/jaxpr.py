@@ -206,30 +206,37 @@ def _jaxpr_hash(closed) -> str:
 @functools.lru_cache(maxsize=128)
 def index_map_report(kind: str, w_total: int,
                      n_runs: int = 0) -> IndexMapReport:
-    """Audit the fused kernels' ``BlockSpec`` index maps for one schedule.
+    """Audit the fused kernels' index maps for one schedule.
 
-    The streaming kernels' grids are shaped by the index maps exported as
-    :data:`repro.kernels.stream.INDEX_MAPS`; a hazard there is a *compile-*
-    or *DMA-time* failure class the plan verifier cannot see from the
-    schedule arrays alone.  Each map is traced abstractly over the
+    The streaming kernels address their operand blocks through the index
+    maps exported as :data:`repro.kernels.stream.INDEX_MAPS`: the panel
+    kernel's ``BlockSpec`` maps over its grid steps, and the block-run
+    kernel's slot reads, which take a work entry and name the A block, the
+    B block and the ``runs`` row its own DMAs copy.  A hazard there is a
+    *compile-* or *DMA-time* failure class the plan verifier cannot see
+    from the schedule arrays alone.  Each map is traced abstractly over the
     scalar-prefetch operands a (W=``w_total``) schedule provides and
     checked for:
 
-    - **block-index shape** — exactly one block coordinate per operand
-      axis, every coordinate a scalar integer (a vector or float output
-      would mis-slice the operand stream);
+    - **block-index shape** — the kernel's count of block coordinates
+      (one slot for the block-run kernel's whole-block copies, one per
+      block axis for a ``BlockSpec``), every coordinate a scalar integer
+      (a vector or float output would mis-slice the operand stream);
     - **purity** — no host-callback primitives inside the map (a callback
       per grid step would serialize the DMA pipeline through the host);
     - **retrace identity** — tracing twice hashes identically, so the
       map cannot leak trace-dependent state into the grid (the
-      ``pallas_call`` would silently recompile per apply).
+      ``pallas_call`` would silently recompile per apply);
+    - **chunk cover** (block-run kernel) — its grid steps of
+      ``run_chunk(W)`` entries each reach every entry below W, and no
+      step is empty.
 
     Results are cached per (kind, W, R) — the checker calls this once per
     distinct schedule shape, not per plan.
     """
-    from ..kernels.stream import INDEX_MAPS
+    from ..kernels.stream import INDEX_MAPS, run_chunk, run_grid_steps
 
-    num_prefetch, maps = INDEX_MAPS[kind]
+    num_prefetch, n_coords, maps = INDEX_MAPS[kind]
     if w_total == 0:
         return IndexMapReport(kind, 0, n_runs, {}, ())
 
@@ -245,6 +252,14 @@ def index_map_report(kind: str, w_total: int,
                 *[jnp.zeros((w_total,), jnp.int32)] * num_prefetch)
 
     diags: List[PlanDiagnostic] = []
+    if kind == "dest":
+        chunk, steps = run_chunk(w_total), run_grid_steps(w_total)
+        if steps * chunk < w_total or (steps - 1) * chunk >= w_total:
+            diags.append(PlanDiagnostic(
+                code="schedule-index-map", severity=ERROR,
+                message=f"{steps} grid step(s) of {chunk} entries do not "
+                        f"cover W={w_total} exactly once without an empty "
+                        "step", location="run_chunk/run_grid_steps"))
     hashes: Dict[str, str] = {}
     for name, fn in maps.items():
         closed = _trace(fn)
@@ -254,13 +269,14 @@ def index_map_report(kind: str, w_total: int,
         bad = [v for v in outs
                if getattr(getattr(v, "aval", None), "shape", None) != ()
                or not jnp.issubdtype(getattr(v, "aval").dtype, jnp.integer)]
-        if len(outs) != 3 or bad:
+        if len(outs) != n_coords or bad:
             diags.append(PlanDiagnostic(
                 code="schedule-index-map", severity=ERROR,
                 message=f"index map returns {len(outs)} output(s) with "
                         f"{len(bad)} non-scalar-integer aval(s); the "
-                        "operand streams are 3-D block stacks addressed by "
-                        "scalar block coordinates", location=loc))
+                        f"{kind!r} kernel addresses its 3-D block stacks by "
+                        f"{n_coords} scalar block coordinate(s)",
+                location=loc))
         prims: Counter = Counter()
         callbacks: Counter = Counter()
         _walk(closed.jaxpr, prims, callbacks, [0.0, 0.0], 1.0)

@@ -694,11 +694,7 @@ def _plan_phase1(a_spec: OperandSpec, b_spec: OperandSpec, *,
     # "configure the hardware": backend-specific pattern-only schedules
     with obs.span("plan.prepare", backend=backend_obj.name) as sp:
         plan.aux = backend_obj.prepare(plan)
-        sched = plan.aux.get("stream_schedule") \
-            if isinstance(plan.aux, dict) else None
-        if sched is not None:
-            sp.set(block_pairs=sched.n_real_work, grid_steps=sched.n_work,
-                   runs=int(sched.n_runs))
+        sp.set(**backend_obj.kernel_attrs(plan))
     return _maybe_verify(plan, verify)
 
 

@@ -135,6 +135,13 @@ class ExecutionBackend(abc.ABC):
         del plan
         return {}
 
+    def kernel_attrs(self, plan) -> Dict[str, int]:
+        """What one apply of the prepared ``plan`` runs on the device, as
+        ``plan.prepare`` span attributes; empty for a backend that runs no
+        work-list kernel."""
+        del plan
+        return {}
+
     def uniform_aux(self, plans) -> None:
         """Make sibling plans' aux schedules shape-uniform so they stack.
 

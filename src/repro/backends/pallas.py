@@ -124,6 +124,25 @@ class PallasBackend(ExecutionBackend):
             aux["dense"] = ()
         return aux
 
+    def kernel_attrs(self, plan) -> Dict[str, int]:
+        """The stream schedule's block pairs, grid steps and runs, and for
+        the block-run kernel whether it holds the A block stack in VMEM
+        (for float32 blocks; N-stationary plans run with B's blocks as A).
+        """
+        from ..kernels.stream import a_resident
+
+        sched = plan.aux.get("stream_schedule")
+        if sched is None:
+            return {}
+        attrs = {"block_pairs": sched.n_real_work,
+                 "grid_steps": sched.grid_steps, "runs": int(sched.n_runs)}
+        if sched.kind == "dest":
+            layout = (plan.b_layout if plan.dataflow.endswith("_n")
+                      else plan.a_layout)
+            bm, bk = layout.block_shape
+            attrs["a_resident"] = int(a_resident(layout.nnzb * bm * bk * 4))
+        return attrs
+
     def uniform_aux(self, plans) -> None:
         """Pad sibling schedules to shared (work, run) extents, in place.
 

@@ -34,10 +34,13 @@ __all__ = [
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bk, bn) — MXU-aligned
 
 
-def compiler_params(dimension_semantics: tuple[str, ...]):
-    """TPU compiler params (grid dimension semantics); ignored when the
+def compiler_params(dimension_semantics: tuple[str, ...],
+                    vmem_limit_bytes: int | None = None):
+    """TPU compiler params (grid dimension semantics, and the VMEM the
+    kernel may use when it needs more than the default); ignored when the
     kernel is interpreted."""
-    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 def grid_spec(num_scalar_prefetch: int, grid, in_specs, out_specs,

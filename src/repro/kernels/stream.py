@@ -20,11 +20,14 @@ boundaries, consumed by exactly two Pallas kernels:
   row panel in VMEM (GAMMA's fiber cache) and each psum merges at its
   follower's column offset immediately.
 
-Both kernels run a 1-D grid over the work list with the operand block
-streams described by scalar-prefetched ``BlockSpec`` index maps — Pallas
-pipelines the per-step DMA, so the next entry's A/B blocks prefetch into
-VMEM while the current entry's ``jnp.dot`` occupies the MXU
-(double-buffering, the paper's 3-tier hierarchy made implicit).
+The block-run kernel walks the work list in chunks of up to
+``MAX_CHUNK`` entries a grid step and stages its operands itself: B
+blocks (and A blocks, when the A stack is too large to hold) stream from
+HBM through a VMEM ring of DMA slots, the next chunk's copies in flight
+while this chunk's dots occupy the MXU, and an A stack that fits
+``A_RESIDENT_BYTES`` is copied into VMEM once per call.  The panel kernel
+runs one grid step per entry with its operand streams described by
+scalar-prefetched ``BlockSpec`` index maps, which Pallas double-buffers.
 
 Every array in a :class:`StreamSchedule` is a pytree child, so schedules
 **stack**: :func:`pad_schedule` pads the work and run axes to shared
@@ -37,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -139,6 +143,13 @@ class StreamSchedule:
     @property
     def oob_row(self) -> int:
         return int(np.asarray(self.oob).reshape(-1)[0])
+
+    @property
+    def grid_steps(self) -> int:
+        """Grid steps of the kernel that consumes this schedule: chunks of
+        :func:`run_chunk` entries (block-run), one per entry (panel)."""
+        return (run_grid_steps(self.n_work) if self.kind == "dest"
+                else self.n_work)
 
     def describe(self) -> dict:
         """The self-description contract as one plain dict (checker/CLI)."""
@@ -286,23 +297,26 @@ def pad_schedule(s: StreamSchedule, w_total: int, r_total: int,
     )
 
 
-# -- scalar-prefetched BlockSpec index maps -------------------------------
+# -- index maps -------------------------------------------------------------
 # Named module-level functions (not inline lambdas) so repro.analysis.jaxpr
 # can trace and audit them by schedule kind without rebuilding a
-# pallas_call.  Each takes the grid step plus the kernel's scalar-prefetch
-# operands and returns the block index tuple for its operand stream.
+# pallas_call.  Each takes a position plus the kernel's scalar-prefetch
+# operands.  The block-run kernel copies whole blocks itself, so its maps
+# take a work entry and give one block slot (of the A stack, the B stack
+# and the ``runs`` output); the panel kernel's are ``BlockSpec`` index maps
+# over its grid step and give one coordinate per block axis.
 
 
-def _dest_a_map(w, sa, sb, fst, lst, rid):
-    return (sa[w], 0, 0)
+def _dest_a_map(e, sa, sb, fst, lst, rid):
+    return sa[e]
 
 
-def _dest_b_map(w, sa, sb, fst, lst, rid):
-    return (sb[w], 0, 0)
+def _dest_b_map(e, sa, sb, fst, lst, rid):
+    return sb[e]
 
 
-def _dest_out_map(w, sa, sb, fst, lst, rid):
-    return (rid[w], 0, 0)
+def _dest_out_map(e, sa, sb, fst, lst, rid):
+    return rid[e]
 
 
 def _panel_a_map(w, sa, sb, cj, fst, lst, rid):
@@ -317,33 +331,160 @@ def _panel_out_map(w, sa, sb, cj, fst, lst, rid):
     return (rid[w], 0, 0)
 
 
-#: per schedule kind: (num_scalar_prefetch, {operand: index map}).  The
-#: checker's jaxpr pass audits exactly these functions; keep them in sync
-#: with the grid specs below.
+#: per schedule kind: (num_scalar_prefetch, coordinates per index,
+#: {operand: index map}).  The checker's jaxpr pass audits exactly these
+#: functions; keep them in sync with the kernels below.
 INDEX_MAPS = {
-    "dest": (5, {"a": _dest_a_map, "b": _dest_b_map, "out": _dest_out_map}),
-    "panel": (6, {"a": _panel_a_map, "b": _panel_b_map,
-                  "out": _panel_out_map}),
+    "dest": (5, 1, {"a": _dest_a_map, "b": _dest_b_map,
+                    "out": _dest_out_map}),
+    "panel": (6, 3, {"a": _panel_a_map, "b": _panel_b_map,
+                     "out": _panel_out_map}),
 }
+
+#: bytes of VMEM the block-run kernel may give to holding the whole A block
+#: stack for the call (a quarter of a v5e core's 128 MiB); a larger stack
+#: streams its blocks through the DMA ring with B's.
+A_RESIDENT_BYTES = 32 << 20
+#: most work entries one grid step of the block-run kernel walks
+MAX_CHUNK = 32
+#: VMEM the block-run kernel asks for beyond its buffers (Mosaic's own
+#: scratch: the dot's result, the cast at an emit)
+VMEM_HEADROOM = 8 << 20
+
+
+def run_chunk(w_total: int) -> int:
+    """Work entries one grid step of the block-run kernel walks."""
+    return max(1, min(MAX_CHUNK, int(w_total)))
+
+
+def run_grid_steps(w_total: int) -> int:
+    """Grid steps of the block-run kernel over ``w_total`` work entries."""
+    return -(-int(w_total) // run_chunk(w_total))
+
+
+def a_resident(a_nbytes: int) -> bool:
+    """Whether the block-run kernel holds an A stack of ``a_nbytes`` in
+    VMEM for the whole call instead of streaming its blocks."""
+    return int(a_nbytes) <= A_RESIDENT_BYTES
 
 
 def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
-                run_id_ref, a_ref, b_ref, o_ref, acc_ref):
-    del a_slot_ref, b_slot_ref, run_id_ref
-    w = pl.program_id(0)
+                run_id_ref, a_hbm, b_hbm, runs_hbm, a_buf, b_buf, psum_ref,
+                acc_ref, a_sem, b_sem, o_sem, started_ref, *stage,
+                chunk: int, w_total: int, resident: bool):
+    """One grid step walks ``chunk`` consecutive work entries.
 
-    # MRN node discipline at block granularity: coordinate changed -> new
-    # fiber; match -> add on the MXU; fiber complete -> emit downstream.
-    @pl.when(is_first_ref[w] == 1)
+    Operands stay in HBM and the kernel copies them itself: each entry's
+    B block (and A block, unless the whole A stack is held in ``a_buf``)
+    into a ring of ``2 * chunk`` VMEM slots, the next chunk's copies
+    started before this chunk's dots.  The chunk's dots run back to back,
+    unrolled with no control flow between them, into ``psum_ref``; the
+    copies and the fold of the entries into runs, in schedule order, are
+    loops.  Runs accumulate in one of two f32
+    slots, alternating per run, and leave by async copy to their ``runs``
+    row; a slot's copy is waited on before the slot is reset and before
+    the last step ends.
+    """
+    prefetch = (a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
+                run_id_ref)
+    stage_ref = stage[0] if stage else acc_ref
+    c = pl.program_id(0)
+    last_step = pl.num_programs(0) - 1
+    tail = w_total % chunk          # entries in a short last chunk, or 0
+
+    def each_entry(step, body):
+        # body(j, e) for the step's entries in order; entries past the
+        # static W exist only in a short last chunk
+        def visit(j, carry):
+            e = step * chunk + j
+            if tail:
+                pl.when(e < w_total)(functools.partial(body, j, e))
+            else:
+                body(j, e)
+            return carry
+        jax.lax.fori_loop(0, chunk, visit, 0)
+
+    def operand_copies(e, slot):
+        copies = [pltpu.make_async_copy(
+            b_hbm.at[_dest_b_map(e, *prefetch)], b_buf.at[slot],
+            b_sem.at[slot])]
+        if not resident:
+            copies.append(pltpu.make_async_copy(
+                a_hbm.at[_dest_a_map(e, *prefetch)], a_buf.at[slot],
+                a_sem.at[slot]))
+        return copies
+
+    def fetch(step):
+        base = (step & 1) * chunk
+
+        def start(j, e):
+            for copy in operand_copies(e, base + j):
+                copy.start()
+        each_entry(step, start)
+
+    def out_copy(s, run):
+        return pltpu.make_async_copy(stage_ref.at[s], runs_hbm.at[run],
+                                     o_sem.at[s])
+
+    @pl.when(c == 0)
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        started_ref[0] = 0
+        if resident:
+            whole_a = pltpu.make_async_copy(a_hbm, a_buf, a_sem.at[0])
+            whole_a.start()
+        fetch(c)
+        if resident:
+            whole_a.wait()
 
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(is_last_ref[w] == 1)
+    @pl.when(c < last_step)
     def _():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+        fetch(c + 1)
+
+    base = (c & 1) * chunk
+
+    def arrive(j, e):
+        for copy in operand_copies(e, base + j):
+            copy.wait()
+    each_entry(c, arrive)
+
+    for j in range(chunk):
+        # an entry past W multiplies a stale slot; its product is not used
+        e = jnp.minimum(c * chunk + j, w_total - 1)
+        a = a_buf[_dest_a_map(e, *prefetch)] if resident else a_buf[base + j]
+        psum_ref[j] = jnp.dot(a, b_buf[base + j],
+                              preferred_element_type=jnp.float32)
+
+    def fold(j, e):
+        # MRN node discipline at block granularity: coordinate changed ->
+        # new fiber; match -> add; fiber complete -> emit downstream.
+        @pl.when(is_first_ref[e] == 1)
+        def _():
+            k = started_ref[0]
+
+            @pl.when(k >= 2)
+            def _():
+                out_copy(k & 1, 0).wait()   # run k-2's emit left the slot
+            acc_ref[k & 1] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
+            started_ref[0] = k + 1
+
+        s = (started_ref[0] - 1) & 1
+        acc_ref[s] += psum_ref[j]
+
+        @pl.when(is_last_ref[e] == 1)
+        def _():
+            if stage:
+                stage_ref[s] = acc_ref[s].astype(stage_ref.dtype)
+            out_copy(s, _dest_out_map(e, *prefetch)).start()
+
+    each_entry(c, fold)
+
+    @pl.when(c == last_step)
+    def _():
+        k = started_ref[0]
+        for back in (1, 2):          # the last two runs' emits
+            @pl.when(k >= back)
+            def _():
+                out_copy((k - back) & 1, 0).wait()
 
 
 def stream_spmm(a_data: jax.Array, b_data: jax.Array, sched: StreamSchedule,
@@ -369,32 +510,44 @@ def stream_spmm(a_data: jax.Array, b_data: jax.Array, sched: StreamSchedule,
                         interpret=bool(resolve_interpret(interpret)))
 
 
-@functools.partial(jax.jit, static_argnames=("out_grid", "out_shape",
-                                             "out_dtype", "interpret"))
-def _stream_spmm(a_data, b_data, sched, *, out_grid, out_shape, out_dtype,
-                 interpret):
+def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
+                interpret):
+    """The block-run kernel's ``(n_runs, bm, bn)`` run sums, ``chunk``
+    entries a grid step, the A stack held in VMEM when ``resident``."""
     w_total = int(sched.a_slot.shape[0])
-    mb, nb = out_grid
     bm, bk = a_data.shape[1], a_data.shape[2]
     bn = b_data.shape[2]
-    if w_total == 0:
-        return jnp.zeros(out_shape, out_dtype)
-
+    ring = 2 * chunk
+    a_buf = pltpu.VMEM(a_data.shape if resident else (ring, bm, bk),
+                       a_data.dtype)
+    b_buf = pltpu.VMEM((ring, bk, bn), b_data.dtype)
+    psum = pltpu.VMEM((chunk, bm, bn), jnp.float32)
+    acc = pltpu.VMEM((2, bm, bn), jnp.float32)
+    vmem = [a_buf, b_buf, psum, acc]
+    if jnp.dtype(out_dtype) != jnp.float32:  # lint: host-ok (static dtype)
+        # runs leave through a staging slot of the output dtype
+        vmem.append(pltpu.VMEM((2, bm, bn), out_dtype))
+    vmem_bytes = sum(math.prod(v.shape) * jnp.dtype(v.dtype).itemsize
+                     for v in vmem)
     spec = grid_spec(
         num_scalar_prefetch=5,
-        grid=(w_total,),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), _dest_a_map),
-            pl.BlockSpec((1, bk, bn), _dest_b_map),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), _dest_out_map),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        grid=(-(-w_total // chunk),),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=vmem[:4] + [
+            pltpu.SemaphoreType.DMA((1 if resident else ring,)),
+            pltpu.SemaphoreType.DMA((ring,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ] + vmem[4:],
     )
-    runs = pl.pallas_call(
-        _run_kernel,
+    return pl.pallas_call(
+        functools.partial(_run_kernel, chunk=chunk, w_total=w_total,
+                          resident=resident),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((sched.n_runs, bm, bn), out_dtype),
-        compiler_params=compiler_params(("arbitrary",)),
+        compiler_params=compiler_params(
+            ("arbitrary",), vmem_limit_bytes=vmem_bytes + VMEM_HEADROOM),
         interpret=interpret,
     )(jnp.asarray(sched.a_slot, jnp.int32),
       jnp.asarray(sched.b_slot, jnp.int32),
@@ -402,6 +555,21 @@ def _stream_spmm(a_data, b_data, sched, *, out_grid, out_shape, out_dtype,
       jnp.asarray(sched.is_last, jnp.int32),
       jnp.asarray(sched.run_id, jnp.int32),
       a_data, b_data)
+
+
+@functools.partial(jax.jit, static_argnames=("out_grid", "out_shape",
+                                             "out_dtype", "interpret"))
+def _stream_spmm(a_data, b_data, sched, *, out_grid, out_shape, out_dtype,
+                 interpret):
+    w_total = int(sched.a_slot.shape[0])
+    mb, nb = out_grid
+    bm, bn = a_data.shape[1], b_data.shape[2]
+    if w_total == 0:
+        return jnp.zeros(out_shape, out_dtype)
+    a_nbytes = a_data.size * jnp.dtype(a_data.dtype).itemsize
+    runs = _block_runs(a_data, b_data, sched, chunk=run_chunk(w_total),
+                       resident=a_resident(a_nbytes), out_dtype=out_dtype,
+                       interpret=interpret)
 
     # Finished fibers stream to DRAM: place runs in the dense C image.
     # Pad runs carry an out-of-bounds row — the scatter drops them.

@@ -16,7 +16,8 @@ Observability (:mod:`repro.obs`): set-up runs under an ``ffn.compress``
 span with ``ffn.mask``, ``plan.phase1`` and ``ffn.pack`` children, and
 counts ``ffn.mask_s`` / ``ffn.pack_s`` (and, through the plan API,
 ``plan.build_s``) into the global registry; ``ffn.block_pairs`` holds the
-block pairs one call of the last planned shape multiplies.  At run time
+block pairs one call of the last planned shape multiplies, and
+``ffn.grid_steps`` the kernel grid steps it runs them in.  At run time
 the matmuls run under the ``jax.named_scope`` names ``ffn.gate`` /
 ``ffn.up`` / ``ffn.down`` and the SiLU·mul under ``ffn.act``, which name
 their device work in a profiler trace and cost nothing per call.
@@ -58,15 +59,23 @@ class PlannedFFN:
         per matmul: each plan's stream-schedule work entries that are real.
         ``None`` for a plan without a stream schedule (reference backend,
         tiled or sharded plans)."""
-        return {"gate": _block_pairs(self.plan_in),
-                "up": _block_pairs(self.plan_in),
-                "down": _block_pairs(self.plan_out)}
+        return self._per_matmul("n_real_work")
+
+    def grid_steps(self) -> Dict[str, Optional[int]]:
+        """Kernel grid steps one call runs, per matmul (the block-run
+        kernel walks several block pairs a step); ``None`` as above."""
+        return self._per_matmul("grid_steps")
+
+    def _per_matmul(self, count: str) -> Dict[str, Optional[int]]:
+        return {"gate": _schedule_count(self.plan_in, count),
+                "up": _schedule_count(self.plan_in, count),
+                "down": _schedule_count(self.plan_out, count)}
 
 
-def _block_pairs(plan) -> Optional[int]:
+def _schedule_count(plan, count: str) -> Optional[int]:
     aux = getattr(plan, "aux", None)
     sched = aux.get("stream_schedule") if isinstance(aux, dict) else None
-    return None if sched is None else sched.n_real_work
+    return None if sched is None else getattr(sched, count)
 
 
 class CompressedFFN:
@@ -165,9 +174,10 @@ class CompressedFFN:
                            self._pack("gate", wg, plan_in),
                            self._pack("up", wu, plan_in),
                            self._pack("down", wd, plan_out))
-        pairs = entry.block_pairs().values()
-        if None not in pairs:
-            obs.get_registry().gauge("ffn.block_pairs").set(sum(pairs))
+        for gauge, counts in (("ffn.block_pairs", entry.block_pairs()),
+                              ("ffn.grid_steps", entry.grid_steps())):
+            if None not in counts.values():
+                obs.get_registry().gauge(gauge).set(sum(counts.values()))
         self._by_tokens[tokens] = entry
         self.plan_builds += 1
         if self.max_shapes is not None \

@@ -67,7 +67,10 @@ def test_plans_built_once_per_token_shape(pruned_ffn):
 def test_block_pairs_are_kept_blocks_times_m_blocks_for_ip_m(pruned_ffn):
     """The program's own per-call work count: for IP, every kept weight
     block meets each of the activations' m-blocks once.  Phase 1 records
-    it, with the schedule's grid steps and runs, on ``plan.prepare``."""
+    it, with the kernel's grid steps (chunks of block pairs), its runs and
+    whether it holds the activation blocks in VMEM, on ``plan.prepare``;
+    the grid steps of the three matmuls also go to ``ffn.grid_steps``."""
+    from repro.kernels.stream import run_chunk
     from repro import obs
     from repro.obs import trace as trace_mod
 
@@ -92,16 +95,26 @@ def test_block_pairs_are_kept_blocks_times_m_blocks_for_ip_m(pruned_ffn):
     assert pairs == {"gate": 3 * kept, "up": 3 * kept, "down": 3 * kept}
     assert obs.get_registry().value("ffn.block_pairs") == 9 * kept
     assert [a["block_pairs"] for a in prepare] == [3 * kept, 3 * kept]
+    steps = []
     for a, plan in zip(prepare, (entry.plan_in, entry.plan_out)):
         sched = plan.aux["stream_schedule"]
-        assert (a["grid_steps"], a["runs"]) == (sched.n_work, sched.n_runs)
+        w = sched.n_work
+        steps.append(-(-w // run_chunk(w)))
+        assert steps[-1] < w
+        assert (a["grid_steps"], a["runs"], a["a_resident"]) == (
+            steps[-1], sched.n_runs, 1)
+    assert entry.grid_steps() == {"gate": steps[0], "up": steps[0],
+                                  "down": steps[1]}
+    assert obs.get_registry().value("ffn.grid_steps") == \
+        2 * steps[0] + steps[1]
 
 
 def test_block_pairs_are_none_without_a_stream_schedule(pruned_ffn):
     cfg, params = pruned_ffn
     comp = compress_ffn(params, tokens=16, block=16, backend="reference")
-    assert comp.specialize(16).block_pairs() == {"gate": None, "up": None,
-                                                 "down": None}
+    entry = comp.specialize(16)
+    assert entry.block_pairs() == {"gate": None, "up": None, "down": None}
+    assert entry.grid_steps() == {"gate": None, "up": None, "down": None}
 
 
 def test_compress_span_tree_and_untraced_counts(pruned_ffn):

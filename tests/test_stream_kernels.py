@@ -14,6 +14,10 @@ The contracts under test, all in interpret mode on CPU:
 - **dense escape** — high-occupancy plans take the plain-MXU matmul hatch
   (``"dense"`` aux marker), the ``dense_threshold`` knob moves the
   boundary, and numerics are unchanged either way;
+- **block-run kernel** — the chunked grid with its operand DMA ring and
+  resident A gives the runs of a per-entry accumulation bit for bit:
+  runs shorter and longer than a chunk, ragged last chunks, stacked
+  schedules under ``lax.scan``;
 - **schedule padding** — ``pad_schedule``'s self-contained pad runs target
   a dropped out-of-bounds row and reject impossible extents;
 - **block autotuning** — backends expose ``tuning_knobs`` and
@@ -23,6 +27,8 @@ The contracts under test, all in interpret mode on CPU:
   MXU-misaligned blocks surface a typed ``block-alignment`` verify_plan
   diagnostic instead of a Mosaic crash.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -145,6 +151,94 @@ def test_dense_threshold_knob_moves_the_boundary():
     np.testing.assert_allclose(np.asarray(plan.apply(a, b)), a @ b,
                                rtol=1e-4, atol=1e-4)
     assert "dense_threshold" in get_backend("pallas").tuning_knobs()
+
+
+# ---------------------------------------------------------------------------
+# Block-run kernel: chunked grid, operand DMA ring, resident A
+# ---------------------------------------------------------------------------
+
+
+def _schedule_of_runs(lengths, n_a, n_b, rng):
+    """A destination-major schedule whose runs have the given lengths."""
+    w, r = sum(lengths), len(lengths)
+    ends = np.cumsum(lengths)
+    is_first = np.zeros(w, np.int32)
+    is_first[ends - np.asarray(lengths)] = 1
+    is_last = np.zeros(w, np.int32)
+    is_last[ends - 1] = 1
+    run_id = np.repeat(np.arange(r), lengths).astype(np.int32)
+    slots = [rng.integers(0, n, w).astype(np.int32) for n in (n_a, n_b)]
+    return StreamSchedule(*slots, np.zeros(w, np.int32), is_first, is_last,
+                          run_id, np.arange(r, dtype=np.int32),
+                          np.zeros(r, np.int32), r)
+
+
+def _runs_in_entry_order(a, b, s, out_dtype):
+    """Plain per-entry accumulation in schedule order: reset on is_first,
+    add each pair's f32 dot, emit the run on is_last."""
+    out = [None] * s.n_runs
+    acc = None
+    for e in range(s.n_work):
+        if s.is_first[e]:
+            acc = jnp.zeros((a.shape[1], b.shape[2]), jnp.float32)
+        acc = acc + jnp.dot(a[s.a_slot[e]], b[s.b_slot[e]],
+                            preferred_element_type=jnp.float32)
+        if s.is_last[e]:
+            out[s.run_id[e]] = np.asarray(acc.astype(out_dtype))
+    return out
+
+
+#: name -> (run lengths of each stacked member, entries a grid step, out
+#: dtype); one member runs unstacked, two run stacked under lax.scan
+BLOCK_RUN_CASES = {
+    "runs-shorter-and-longer-than-chunk": ([[2, 7, 1, 12, 3]], 4,
+                                           jnp.float32),
+    "several-runs-end-in-one-chunk": ([[1, 1, 1, 2, 3, 1]], 8, jnp.float32),
+    "w-below-chunk": ([[2, 1]], 16, jnp.float32),
+    "w-not-a-multiple-of-chunk": ([[5, 6]], 4, jnp.float32),
+    "one-entry-a-step": ([[3, 1, 2]], 1, jnp.float32),
+    "bf16-output": ([[4, 1, 3]], 3, jnp.bfloat16),
+    "stacked-under-scan": ([[3, 2, 4], [1, 5]], 4, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["a-resident", "a-ring"])
+@pytest.mark.parametrize("case", list(BLOCK_RUN_CASES))
+def test_block_run_kernel_matches_per_entry_order(case, resident):
+    """The chunked kernel gives, bit for bit, the runs of a per-entry
+    accumulation in schedule order, with A held in VMEM or (as for a
+    stack over ``A_RESIDENT_BYTES``) streamed through the DMA ring."""
+    from repro.kernels.stream import (A_RESIDENT_BYTES, _block_runs,
+                                      a_resident)
+
+    members, chunk, out_dtype = BLOCK_RUN_CASES[case]
+    rng = np.random.default_rng(sum(map(len, members)) + chunk)
+    a = jnp.asarray(rng.standard_normal((5, 8, 16)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((7, 16, 8)), jnp.float32)
+    scheds = [_schedule_of_runs(lengths, 5, 7, rng) for lengths in members]
+    want = [_runs_in_entry_order(a, b, s, out_dtype) for s in scheds]
+
+    def runs(sched):
+        return _block_runs(a, b, sched, chunk=chunk, resident=resident,
+                           out_dtype=out_dtype, interpret=True)
+
+    if len(scheds) == 1:
+        got = [np.asarray(runs(scheds[0]))]
+    else:
+        w_max = max(s.n_work for s in scheds)
+        r_total = max(s.n_runs for s in scheds) + 1
+        padded = [pad_schedule(s, w_max, r_total, oob_row=99)
+                  for s in scheds]
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
+        got = np.asarray(jax.lax.scan(lambda c, s: (c, runs(s)), 0,
+                                      stacked)[1])
+    for g, w in zip(got, want):
+        assert g.dtype == np.dtype(out_dtype)
+        for r, run in enumerate(w):
+            np.testing.assert_array_equal(g[r], run, err_msg=f"run {r}")
+    assert a_resident(A_RESIDENT_BYTES)
+    assert not a_resident(A_RESIDENT_BYTES + 1)
 
 
 # ---------------------------------------------------------------------------

@@ -107,6 +107,45 @@ def test_stream_kernel_compiles_for_v5e(one_chip, w_gate, dataflow, kernel,
     assert compiled.memory_analysis() is not None
 
 
+#: the benchmark's matmuls: (tokens, d_in, d_out) of the Mixtral-8x7B
+#: expert at decode16 and prefill1024 and of Chameleon-34B at decode32
+BENCH_MATMULS = [(16, 4096, 14336), (16, 14336, 4096), (32, 8192, 22016),
+                 (32, 22016, 8192), (1024, 14336, 4096)]
+
+
+@pytest.mark.parametrize("tokens,d_in,d_out", BENCH_MATMULS)
+def test_block_run_kernel_compiles_at_benchmark_widths(one_chip, tokens,
+                                                       d_in, d_out):
+    """The block-run kernel at the benchmark's real shapes (``ip_m``, 25%
+    of the weight blocks kept): its VMEM (resident activations or the
+    ring, which the 1024-token down projection's 56 MiB of activation
+    blocks takes) fits, and it stays one ``_stream_spmm`` custom-call."""
+    from repro.kernels.stream import StreamSchedule, a_resident
+
+    mb, kb, nb = -(-tokens // BLOCK), d_in // BLOCK, d_out // BLOCK
+    kept = kb * nb // 4
+    w, r = mb * kept, mb * nb
+
+    def ints(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    sched = StreamSchedule(*[ints(w)] * 6, ints(r), ints(r), r, "dest",
+                           ints(1), ints(1), ints(1))
+    a = jax.ShapeDtypeStruct((mb * kb, BLOCK, BLOCK), jnp.float32,
+                             sharding=one_chip)
+    b = jax.ShapeDtypeStruct((kept, BLOCK, BLOCK), jnp.float32,
+                             sharding=one_chip)
+    assert a_resident(mb * kb * BLOCK * BLOCK * 4) == (tokens < 1024
+                                                       or d_in < d_out)
+    text = _stream_spmm.lower(a, b, sched, out_grid=(mb, nb),
+                              out_shape=(tokens, d_out),
+                              out_dtype=jnp.float32,
+                              interpret=False).compile().as_text()
+    kernels = [line.split("=")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and kernels[0].startswith("%_stream_spmm")
+
+
 def test_ffn_kernels_carry_their_matmul_scope(one_chip, w_gate):
     """Each of ``sparse_ffn_apply``'s three kernels keeps its matmul's
     ``jax.named_scope`` in its ``op_name`` metadata after compilation: the
@@ -147,3 +186,4 @@ def test_ffn_kernels_carry_their_matmul_scope(one_chip, w_gate):
     scopes = sorted(re.search(r'op_name="[^"]*?/(ffn\.\w+)/', line).group(1)
                     for line in kernels)
     assert scopes == ["ffn.down", "ffn.gate", "ffn.up"]
+    assert all(line.lstrip().startswith("%_stream_spmm") for line in kernels)
