@@ -62,6 +62,8 @@ __all__ = [
     "pad_schedule",
     "stream_spmm",
     "stream_panel_spmm",
+    "row_schedules",
+    "grouped_stream_spmm",
 ]
 
 #: the two kernel disciplines a schedule can target: ``"dest"`` is the
@@ -369,9 +371,8 @@ def a_resident(a_nbytes: int) -> bool:
 
 
 def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
-                run_id_ref, a_hbm, b_hbm, runs_hbm, a_buf, b_buf, psum_ref,
-                acc_ref, a_sem, b_sem, o_sem, started_ref, *stage,
-                chunk: int, w_total: int, resident: bool):
+                run_id_ref, *refs, chunk: int, w_total: int, resident: bool,
+                live: bool = False):
     """One grid step walks ``chunk`` consecutive work entries.
 
     Operands stay in HBM and the kernel copies them itself: each entry's
@@ -384,9 +385,18 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
     slots, alternating per run, and leave by async copy to their ``runs``
     row; a slot's copy is waited on before the slot is reset and before
     the last step ends.
+
+    With ``live`` a sixth scalar operand holds the count of live entries,
+    known only on the device: entries from it on are neither fetched,
+    multiplied nor folded, and a step with no live entry runs no dot.  The
+    live entries must end on a run boundary.
     """
     prefetch = (a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
                 run_id_ref)
+    if live:
+        n_live_ref, *refs = refs
+    (a_hbm, b_hbm, runs_hbm, a_buf, b_buf, psum_ref, acc_ref, a_sem, b_sem,
+     o_sem, started_ref, *stage) = refs
     stage_ref = stage[0] if stage else acc_ref
     c = pl.program_id(0)
     last_step = pl.num_programs(0) - 1
@@ -394,15 +404,25 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
 
     def each_entry(step, body):
         # body(j, e) for the step's entries in order; entries past the
-        # static W exist only in a short last chunk
+        # static W exist only in a short last chunk, and past the live
+        # count only when it is given (a step with none skips the loop)
         def visit(j, carry):
             e = step * chunk + j
-            if tail:
+            if live:
+                pl.when(e < n_live_ref[0])(functools.partial(body, j, e))
+            elif tail:
                 pl.when(e < w_total)(functools.partial(body, j, e))
             else:
                 body(j, e)
             return carry
-        jax.lax.fori_loop(0, chunk, visit, 0)
+
+        def walk():
+            jax.lax.fori_loop(0, chunk, visit, 0)
+
+        if live:
+            pl.when(step * chunk < n_live_ref[0])(walk)
+        else:
+            walk()
 
     def operand_copies(e, slot):
         copies = [pltpu.make_async_copy(
@@ -447,12 +467,20 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
             copy.wait()
     each_entry(c, arrive)
 
-    for j in range(chunk):
-        # an entry past W multiplies a stale slot; its product is not used
-        e = jnp.minimum(c * chunk + j, w_total - 1)
-        a = a_buf[_dest_a_map(e, *prefetch)] if resident else a_buf[base + j]
-        psum_ref[j] = jnp.dot(a, b_buf[base + j],
-                              preferred_element_type=jnp.float32)
+    def dots():
+        for j in range(chunk):
+            # an entry past W (or the live count) multiplies a stale slot;
+            # its product is not used
+            e = jnp.minimum(c * chunk + j, w_total - 1)
+            a = (a_buf[_dest_a_map(e, *prefetch)] if resident
+                 else a_buf[base + j])
+            psum_ref[j] = jnp.dot(a, b_buf[base + j],
+                                  preferred_element_type=jnp.float32)
+
+    if live:
+        pl.when(c * chunk < n_live_ref[0])(dots)
+    else:
+        dots()
 
     def fold(j, e):
         # MRN node discipline at block granularity: coordinate changed ->
@@ -511,9 +539,13 @@ def stream_spmm(a_data: jax.Array, b_data: jax.Array, sched: StreamSchedule,
 
 
 def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
-                interpret):
+                interpret, n_live=None):
     """The block-run kernel's ``(n_runs, bm, bn)`` run sums, ``chunk``
-    entries a grid step, the A stack held in VMEM when ``resident``."""
+    entries a grid step, the A stack held in VMEM when ``resident``.
+
+    ``n_live`` (a traced int32 scalar, or None for every entry) stops the
+    walk after that many entries; runs it does not reach are not written.
+    """
     w_total = int(sched.a_slot.shape[0])
     bm, bk = a_data.shape[1], a_data.shape[2]
     bn = b_data.shape[2]
@@ -529,8 +561,10 @@ def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
         vmem.append(pltpu.VMEM((2, bm, bn), out_dtype))
     vmem_bytes = sum(math.prod(v.shape) * jnp.dtype(v.dtype).itemsize
                      for v in vmem)
+    live = () if n_live is None else (
+        jnp.reshape(jnp.asarray(n_live, jnp.int32), (1,)),)
     spec = grid_spec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + len(live),
         grid=(-(-w_total // chunk),),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -543,7 +577,7 @@ def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
     )
     return pl.pallas_call(
         functools.partial(_run_kernel, chunk=chunk, w_total=w_total,
-                          resident=resident),
+                          resident=resident, live=bool(live)),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((sched.n_runs, bm, bn), out_dtype),
         compiler_params=compiler_params(
@@ -554,7 +588,7 @@ def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
       jnp.asarray(sched.is_first, jnp.int32),
       jnp.asarray(sched.is_last, jnp.int32),
       jnp.asarray(sched.run_id, jnp.int32),
-      a_data, b_data)
+      *live, a_data, b_data)
 
 
 @functools.partial(jax.jit, static_argnames=("out_grid", "out_shape",
@@ -578,6 +612,107 @@ def _stream_spmm(a_data, b_data, sched, *, out_grid, out_shape, out_dtype,
              jnp.asarray(sched.run_cj, jnp.int32)].set(runs)
     c = c.swapaxes(1, 2).reshape(mb * bm, nb * bn)
     return c[: out_shape[0], : out_shape[1]]
+
+
+# -- grouped block rows: many members, rows routed on the device -----------
+
+
+def _row_schedule(mask: np.ndarray) -> StreamSchedule:
+    """The ``ip_m`` schedule of one dense block row against a weight of
+    block pattern ``mask`` (Kb, Nb), with one run for every output column,
+    in column order: a column's kept blocks in k order, or for a column
+    with none one entry against the zero block (B slot -1).  A slots are
+    block columns of the row; B slots count the kept blocks in
+    column-major (BCSC) order."""
+    mask = np.asarray(mask, bool)
+    cols = mask.copy()
+    cols[0, ~mask.any(0)] = True
+    js, ks = np.nonzero(cols.T)
+    kept = mask[ks, js]
+    b_slot = np.where(kept, np.cumsum(kept) - 1, -1).astype(np.int32)
+    is_first, is_last, run_id = _runs_from_boundaries(js[1:] != js[:-1],
+                                                      js.size)
+    nb = mask.shape[1]
+    return StreamSchedule(ks.astype(np.int32), b_slot, js.astype(np.int32),
+                          is_first, is_last, run_id, np.zeros(nb, np.int32),
+                          np.arange(nb, dtype=np.int32), nb, "dest")
+
+
+def row_schedules(masks: np.ndarray) -> StreamSchedule:
+    """Phase 1 for a stack of members at once: each member's
+    :func:`_row_schedule`, padded to shared extents and stacked on a
+    leading member axis.  Run ``j`` of every member is output column
+    ``j``; a member padded to the longest work list gets pad entries whose
+    one run, after the columns' runs, is dropped.
+
+    ``masks``: (E, Kb, Nb) block patterns, one per member.
+    """
+    scheds = [_row_schedule(m) for m in np.asarray(masks, bool)]
+    w_max = max(s.n_work for s in scheds)
+    ragged = any(s.n_work < w_max for s in scheds)
+    r_total = scheds[0].n_runs + int(ragged)
+    padded = [pad_schedule(s, w_max, r_total, oob_row=1) for s in scheds]
+    return jax.tree.map(lambda *xs: np.stack(xs), *padded)
+
+
+def grouped_stream_spmm(a_data: jax.Array, b_data: jax.Array,
+                        members: StreamSchedule, slot_member: jax.Array,
+                        n_live: jax.Array, *, out_cols: int,
+                        out_dtype=jnp.float32,
+                        interpret: bool | None = None) -> jax.Array:
+    """Row panels ``A_s @ B_{member(s)}`` for the live slots, through the
+    block-run kernel, with the routing known only on the device.
+
+    ``a_data`` holds ``S`` slots of ``Ka`` blocks each, slot-major: slot
+    ``s`` is one dense block row.  ``b_data`` holds ``E`` members of ``Wb``
+    packed blocks each, member-major, in the order of ``members``
+    (:func:`row_schedules`), then one zero block.  ``slot_member`` (S,)
+    names each slot's member and ``n_live`` how many leading slots are
+    live.  The work list is every slot's copy of its member's schedule,
+    built here on the device; the kernel walks the ``n_live`` live slots'
+    entries and fetches nothing past them.  Returns the output's blocks,
+    ``(S, out_cols / bn, bm, bn)``, slot-major as ``a_data`` is: the
+    kernel's runs as they are.  Dead slots' blocks are not written.  One
+    compiled program serves every routing.
+    """
+    return _grouped_spmm(a_data, b_data, members, slot_member, n_live,
+                         out_cols=int(out_cols), out_dtype=out_dtype,
+                         interpret=bool(resolve_interpret(interpret)))
+
+
+@functools.partial(jax.jit, static_argnames=("out_cols", "out_dtype",
+                                             "interpret"))
+def _grouped_spmm(a_data, b_data, members, slot_member, n_live, *, out_cols,
+                  out_dtype, interpret):
+    n_slots = int(slot_member.shape[0])
+    n_members, w = members.a_slot.shape
+    r = members.n_runs
+    ka = a_data.shape[0] // n_slots
+    wb = (b_data.shape[0] - 1) // n_members
+    bm, bn = a_data.shape[1], b_data.shape[2]
+    m = jnp.asarray(slot_member, jnp.int32)
+    s = jnp.arange(n_slots, dtype=jnp.int32)[:, None]
+    n_live = jnp.asarray(n_live, jnp.int32)
+
+    def per_slot(x):
+        return jnp.asarray(x, jnp.int32)[m]
+
+    b_slot = per_slot(members.b_slot)
+    flat = lambda x: x.reshape(-1)  # noqa: E731
+    sched = StreamSchedule(
+        flat(s * ka + per_slot(members.a_slot)),
+        flat(jnp.where(b_slot < 0, n_members * wb, m[:, None] * wb + b_slot)),
+        flat(per_slot(members.cj)),
+        flat(per_slot(members.is_first)), flat(per_slot(members.is_last)),
+        flat(s * r + per_slot(members.run_id)),
+        jnp.zeros(n_slots * r, jnp.int32), jnp.zeros(n_slots * r, jnp.int32),
+        n_slots * r, "dest", jnp.reshape(n_live * w, (1,)),
+        jnp.reshape(n_live * r, (1,)), jnp.full((1,), -1, jnp.int32))
+    a_nbytes = a_data.size * jnp.dtype(a_data.dtype).itemsize
+    runs = _block_runs(a_data, b_data, sched, chunk=run_chunk(n_slots * w),
+                       resident=a_resident(a_nbytes), out_dtype=out_dtype,
+                       interpret=interpret, n_live=n_live * w)
+    return runs.reshape(n_slots, r, bm, bn)[:, :out_cols // bn]
 
 
 def _panel_kernel(a_slot_ref, b_slot_ref, cj_ref, is_first_ref, is_last_ref,

@@ -189,7 +189,9 @@ def _runs_in_entry_order(a, b, s, out_dtype):
 
 
 #: name -> (run lengths of each stacked member, entries a grid step, out
-#: dtype); one member runs unstacked, two run stacked under lax.scan
+#: dtype[, live entries]); one member runs unstacked, two run stacked under
+#: lax.scan; with a live count the kernel stops after that many entries
+#: (on a run boundary) and only the runs before it are compared
 BLOCK_RUN_CASES = {
     "runs-shorter-and-longer-than-chunk": ([[2, 7, 1, 12, 3]], 4,
                                            jnp.float32),
@@ -199,6 +201,10 @@ BLOCK_RUN_CASES = {
     "one-entry-a-step": ([[3, 1, 2]], 1, jnp.float32),
     "bf16-output": ([[4, 1, 3]], 3, jnp.bfloat16),
     "stacked-under-scan": ([[3, 2, 4], [1, 5]], 4, jnp.float32),
+    "live-count-stops-mid-step": ([[2, 7, 1, 12, 3]], 4, jnp.float32, 10),
+    "live-count-at-a-step-edge": ([[4, 4, 3]], 4, jnp.float32, 8),
+    "live-count-zero": ([[3, 1, 2]], 2, jnp.float32, 0),
+    "live-count-whole-list": ([[5, 6]], 4, jnp.bfloat16, 11),
 }
 
 
@@ -208,11 +214,12 @@ BLOCK_RUN_CASES = {
 def test_block_run_kernel_matches_per_entry_order(case, resident):
     """The chunked kernel gives, bit for bit, the runs of a per-entry
     accumulation in schedule order, with A held in VMEM or (as for a
-    stack over ``A_RESIDENT_BYTES``) streamed through the DMA ring."""
+    stack over ``A_RESIDENT_BYTES``) streamed through the DMA ring, and
+    with a live-entry count read on the device."""
     from repro.kernels.stream import (A_RESIDENT_BYTES, _block_runs,
                                       a_resident)
 
-    members, chunk, out_dtype = BLOCK_RUN_CASES[case]
+    members, chunk, out_dtype, *live = BLOCK_RUN_CASES[case]
     rng = np.random.default_rng(sum(map(len, members)) + chunk)
     a = jnp.asarray(rng.standard_normal((5, 8, 16)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((7, 16, 8)), jnp.float32)
@@ -221,7 +228,12 @@ def test_block_run_kernel_matches_per_entry_order(case, resident):
 
     def runs(sched):
         return _block_runs(a, b, sched, chunk=chunk, resident=resident,
-                           out_dtype=out_dtype, interpret=True)
+                           out_dtype=out_dtype, interpret=True,
+                           n_live=jnp.int32(live[0]) if live else None)
+
+    if live:        # the runs that end before the live count
+        ends = np.cumsum(members[0])
+        want = [w[:int(np.sum(ends <= live[0]))] for w in want]
 
     if len(scheds) == 1:
         got = [np.asarray(runs(scheds[0]))]

@@ -187,3 +187,109 @@ def test_ffn_kernels_carry_their_matmul_scope(one_chip, w_gate):
                     for line in kernels)
     assert scopes == ["ffn.down", "ffn.gate", "ffn.up"]
     assert all(line.lstrip().startswith("%_stream_spmm") for line in kernels)
+
+
+def _expert_masks(n, kb, nb, seed=0):
+    """``n`` experts' block masks with an exact 25% of the blocks kept."""
+    rng = np.random.default_rng(seed)
+    masks = np.zeros((n, kb * nb), bool)
+    for m in masks:
+        m[rng.permutation(kb * nb)[: kb * nb // 4]] = True
+    return masks.reshape(n, kb, nb)
+
+
+#: DeepSeek-V3's routed experts: hidden 7168, expert width 2048; 32 held
+#: experts, 1024 tokens routed top-8
+MOE_D, MOE_F, MOE_HELD, MOE_TOKENS = 7168, 2048, 32, 1024
+
+
+@pytest.mark.parametrize("d_in,d_out", [(MOE_D, MOE_F), (MOE_F, MOE_D)])
+def test_grouped_kernel_compiles_at_expert_widths(one_chip, d_in, d_out):
+    """The grouped block-run kernel for 32 stacked experts and every slot
+    1024 tokens routed top-8 can fill: its ring fits VMEM and it stays one
+    ``_grouped_spmm`` custom-call."""
+    from repro.kernels.stream import _grouped_spmm, row_schedules
+    from repro.models.sparse_moe import n_slots
+
+    slots = n_slots(MOE_TOKENS, 8, MOE_HELD, BLOCK)
+    sched = row_schedules(_expert_masks(MOE_HELD, d_in // BLOCK,
+                                        d_out // BLOCK))
+    kept = d_in * d_out // BLOCK ** 2 // 4
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _grouped_spmm.lower(
+        sds((slots * d_in // BLOCK, BLOCK, BLOCK), jnp.float32),
+        sds((MOE_HELD * kept + 1, BLOCK, BLOCK), jnp.float32),
+        jax.tree.map(lambda x: sds(x.shape), sched), sds((slots,)), sds(()),
+        out_cols=d_out, out_dtype=jnp.float32,
+        interpret=False).compile().as_text()
+    kernels = [line.split("=")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert slots == 95 and len(kernels) == 1
+    assert kernels[0].startswith("%_grouped_spmm")
+
+
+def test_moe_layer_kernels_carry_their_scopes(one_chip, monkeypatch):
+    """The whole MoE layer at DeepSeek-V3's widths compiles for a v5e: its
+    three grouped kernels under ``moe.experts`` and the shared expert's
+    three kernels under ``moe.shared``, each in its matmul's ``ffn.*``
+    scope."""
+    import re
+
+    from repro import config
+    from repro.kernels.stream import row_schedules
+    from repro.models.sparse_linear import PlannedFFN, compress_ffn
+    from repro.models.sparse_moe import (ExpertStack, RouterSpec,
+                                         sparse_moe_apply)
+
+    monkeypatch.setattr(config, "interpret_default", lambda: False)
+    masks = _expert_masks(MOE_HELD, MOE_D // BLOCK, MOE_F // BLOCK)
+
+    def sds(x, dtype=None):
+        shape = x if isinstance(x, tuple) else np.shape(x)
+        dtype = dtype or (x.dtype if hasattr(x, "dtype") else jnp.float32)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s_in = row_schedules(masks)
+    s_out = row_schedules(masks.transpose(0, 2, 1))
+    blocks = (BLOCK, BLOCK)
+    kept = MOE_D * MOE_F // BLOCK ** 2 // 4
+    experts = ExpertStack(
+        sds((MOE_HELD * kept + 1, *blocks)),
+        sds((MOE_HELD * kept + 1, *blocks)),
+        sds((MOE_HELD * kept + 1, *blocks)),
+        jax.tree.map(sds, s_in), jax.tree.map(sds, s_out), MOE_D, MOE_F)
+    mask = _expert_masks(1, MOE_D // BLOCK, MOE_F // BLOCK, seed=1)[0]
+    full = np.repeat(np.repeat(mask, BLOCK, 0), BLOCK, 1).astype(np.float32)
+    params = {"w_gate": {"w": full}, "w_up": {"w": full},
+              "w_down": {"w": np.ascontiguousarray(full.T)},
+              "block_mask": mask.astype(np.float32)}
+    entry = compress_ffn(params, tokens=MOE_TOKENS, backend="pallas",
+                         block=BLOCK, verify=False).specialize(MOE_TOKENS)
+
+    class Fixed:
+        def __init__(self, weights):
+            self.entry = PlannedFFN(entry.plan_in, entry.plan_out, *weights)
+
+        def specialize(self, n):
+            return self.entry
+
+    weights = jax.tree.map(sds, (entry.w_gate, entry.w_up, entry.w_down))
+    spec = RouterSpec(n_experts=256, n_group=8, topk_group=4, top_k=8,
+                      scale=2.5)
+    text = jax.jit(lambda h, w_r, b, e, w: sparse_moe_apply(
+        h, w_r, b, e, Fixed(w), spec)).lower(
+            sds((MOE_TOKENS, MOE_D)), sds((MOE_D, 256)), sds((256,)),
+            experts, weights).compile().as_text()
+    kernels = sorted(
+        (line.lstrip().split(".")[0],
+         re.search(r'op_name="[^"]*?/(moe\.\w+)/(ffn\.\w+)/', line).groups())
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == sorted(
+        [("%_grouped_spmm", ("moe.experts", f"ffn.{m}"))
+         for m in ("gate", "up", "down")] +
+        [("%_stream_spmm", ("moe.shared", f"ffn.{m}"))
+         for m in ("gate", "up", "down")])
