@@ -25,7 +25,9 @@ The block-run kernel walks the work list in chunks of up to
 blocks (and A blocks, when the A stack is too large to hold) stream from
 HBM through a VMEM ring of DMA slots, the next chunk's copies in flight
 while this chunk's dots occupy the MXU, and an A stack that fits
-``A_RESIDENT_BYTES`` is copied into VMEM once per call.  The panel kernel
+``A_RESIDENT_BYTES`` is copied into VMEM once per call.  A grouped call
+whose stack does not fit holds its slots' A row panels instead, two at a
+time, each copied in once while the slot before it runs.  The panel kernel
 runs one grid step per entry with its operand streams described by
 scalar-prefetched ``BlockSpec`` index maps, which Pallas double-buffers.
 
@@ -64,6 +66,7 @@ __all__ = [
     "stream_panel_spmm",
     "row_schedules",
     "grouped_stream_spmm",
+    "grouped_a_mode",
 ]
 
 #: the two kernel disciplines a schedule can target: ``"dest"`` is the
@@ -370,9 +373,20 @@ def a_resident(a_nbytes: int) -> bool:
     return int(a_nbytes) <= A_RESIDENT_BYTES
 
 
+def grouped_a_mode(n_slots: int, ka: int, block_nbytes: int) -> str:
+    """How the grouped block-run kernel holds an A side of ``n_slots``
+    slots of ``ka`` blocks of ``block_nbytes`` each: ``"resident"``, the
+    whole stack in VMEM for the call, when it fits ``A_RESIDENT_BYTES``;
+    else ``"panel"``, the live slots' row panels two at a time, when two
+    fit; else ``"stream"``, each entry's A block through the DMA ring."""
+    if a_resident(n_slots * ka * block_nbytes):
+        return "resident"
+    return "panel" if a_resident(2 * ka * block_nbytes) else "stream"
+
+
 def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
                 run_id_ref, *refs, chunk: int, w_total: int, resident: bool,
-                live: bool = False):
+                live: bool = False, panel: Tuple[int, int] | None = None):
     """One grid step walks ``chunk`` consecutive work entries.
 
     Operands stay in HBM and the kernel copies them itself: each entry's
@@ -390,6 +404,15 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
     known only on the device: entries from it on are neither fetched,
     multiplied nor folded, and a step with no live entry runs no dot.  The
     live entries must end on a run boundary.
+
+    With ``panel = (ka, w)`` the entries come in slots of ``w`` that read
+    only their slot's ``ka`` A blocks, which lie together in the A stack,
+    and ``a_slot`` indexes a two-panel buffer ``a_buf`` (slot ``s``'s
+    blocks in half ``s & 1``).  A slot's panel is copied in once, by one
+    DMA started in the step after the one that holds the previous slot's
+    first entry and waited on in the step that holds its own; a grid step
+    (``chunk <= w``) spans at most two slots.  Slots past the live count
+    fetch no panel.
     """
     prefetch = (a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
                 run_id_ref)
@@ -401,6 +424,7 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
     c = pl.program_id(0)
     last_step = pl.num_programs(0) - 1
     tail = w_total % chunk          # entries in a short last chunk, or 0
+    streamed = not resident and panel is None
 
     def each_entry(step, body):
         # body(j, e) for the step's entries in order; entries past the
@@ -428,7 +452,7 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
         copies = [pltpu.make_async_copy(
             b_hbm.at[_dest_b_map(e, *prefetch)], b_buf.at[slot],
             b_sem.at[slot])]
-        if not resident:
+        if streamed:
             copies.append(pltpu.make_async_copy(
                 a_hbm.at[_dest_a_map(e, *prefetch)], a_buf.at[slot],
                 a_sem.at[slot]))
@@ -446,12 +470,29 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
         return pltpu.make_async_copy(stage_ref.at[s], runs_hbm.at[run],
                                      o_sem.at[s])
 
+    if panel:
+        ka, w = panel
+        n_entries = n_live_ref[0] if live else jnp.int32(w_total)
+
+        def slot_panel(p):
+            return pltpu.make_async_copy(a_hbm.at[pl.ds(p * ka, ka)],
+                                         a_buf.at[pl.ds((p & 1) * ka, ka)],
+                                         a_sem.at[p & 1])
+
+        def first_slot(step):
+            # the slot whose first entry may lie in ``step``, and whether
+            # it does and is live
+            p = jax.lax.div(step * chunk + w - 1, w)
+            return p, p * w < jnp.minimum((step + 1) * chunk, n_entries)
+
     @pl.when(c == 0)
     def _():
         started_ref[0] = 0
         if resident:
             whole_a = pltpu.make_async_copy(a_hbm, a_buf, a_sem.at[0])
             whole_a.start()
+        if panel:
+            pl.when(n_entries > 0)(lambda: slot_panel(0).start())
         fetch(c)
         if resident:
             whole_a.wait()
@@ -460,20 +501,31 @@ def _run_kernel(a_slot_ref, b_slot_ref, is_first_ref, is_last_ref,
     def _():
         fetch(c + 1)
 
+    if panel:
+        # the next slot's panel, into the half the slot before last held
+        q, q_first = first_slot(jnp.maximum(c - 1, 0))
+
+        @pl.when((c > 0) & q_first & ((q + 1) * w < n_entries))
+        def _():
+            slot_panel(q + 1).start()
+
     base = (c & 1) * chunk
 
     def arrive(j, e):
         for copy in operand_copies(e, base + j):
             copy.wait()
     each_entry(c, arrive)
+    if panel:
+        p, p_first = first_slot(c)
+        pl.when(p_first)(lambda: slot_panel(p).wait())
 
     def dots():
         for j in range(chunk):
             # an entry past W (or the live count) multiplies a stale slot;
             # its product is not used
             e = jnp.minimum(c * chunk + j, w_total - 1)
-            a = (a_buf[_dest_a_map(e, *prefetch)] if resident
-                 else a_buf[base + j])
+            a = (a_buf[base + j] if streamed
+                 else a_buf[_dest_a_map(e, *prefetch)])
             psum_ref[j] = jnp.dot(a, b_buf[base + j],
                                   preferred_element_type=jnp.float32)
 
@@ -539,19 +591,33 @@ def stream_spmm(a_data: jax.Array, b_data: jax.Array, sched: StreamSchedule,
 
 
 def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
-                interpret, n_live=None):
+                interpret, n_live=None, panel=None):
     """The block-run kernel's ``(n_runs, bm, bn)`` run sums, ``chunk``
     entries a grid step, the A stack held in VMEM when ``resident``.
 
     ``n_live`` (a traced int32 scalar, or None for every entry) stops the
     walk after that many entries; runs it does not reach are not written.
+    ``panel = (ka, w)``: entry ``e`` belongs to slot ``e // w`` and reads
+    only A blocks ``slot * ka`` to ``slot * ka + ka - 1``; the kernel
+    holds the slots' row panels in VMEM two at a time (``chunk <= w``).
     """
     w_total = int(sched.a_slot.shape[0])
     bm, bk = a_data.shape[1], a_data.shape[2]
     bn = b_data.shape[2]
     ring = 2 * chunk
-    a_buf = pltpu.VMEM(a_data.shape if resident else (ring, bm, bk),
-                       a_data.dtype)
+    a_slot = jnp.asarray(sched.a_slot, jnp.int32)
+    if panel:
+        ka, w = panel
+        if chunk > w:
+            raise ValueError(f"a grid step of {chunk} entries spans more "
+                             f"than two slots of {w}")
+        # each block's place in its slot's half of the two-panel buffer
+        slot = jnp.arange(w_total, dtype=jnp.int32) // w
+        a_slot = a_slot - (slot - (slot & 1)) * ka
+        a_shape = (2 * ka, bm, bk)
+    else:
+        a_shape = a_data.shape if resident else (ring, bm, bk)
+    a_buf = pltpu.VMEM(a_shape, a_data.dtype)
     b_buf = pltpu.VMEM((ring, bk, bn), b_data.dtype)
     psum = pltpu.VMEM((chunk, bm, bn), jnp.float32)
     acc = pltpu.VMEM((2, bm, bn), jnp.float32)
@@ -569,7 +635,8 @@ def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=vmem[:4] + [
-            pltpu.SemaphoreType.DMA((1 if resident else ring,)),
+            pltpu.SemaphoreType.DMA((2 if panel else 1 if resident
+                                     else ring,)),
             pltpu.SemaphoreType.DMA((ring,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),
@@ -577,13 +644,13 @@ def _block_runs(a_data, b_data, sched, *, chunk, resident, out_dtype,
     )
     return pl.pallas_call(
         functools.partial(_run_kernel, chunk=chunk, w_total=w_total,
-                          resident=resident, live=bool(live)),
+                          resident=resident, live=bool(live), panel=panel),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((sched.n_runs, bm, bn), out_dtype),
         compiler_params=compiler_params(
             ("arbitrary",), vmem_limit_bytes=vmem_bytes + VMEM_HEADROOM),
         interpret=interpret,
-    )(jnp.asarray(sched.a_slot, jnp.int32),
+    )(a_slot,
       jnp.asarray(sched.b_slot, jnp.int32),
       jnp.asarray(sched.is_first, jnp.int32),
       jnp.asarray(sched.is_last, jnp.int32),
@@ -670,9 +737,11 @@ def grouped_stream_spmm(a_data: jax.Array, b_data: jax.Array,
     names each slot's member and ``n_live`` how many leading slots are
     live.  The work list is every slot's copy of its member's schedule,
     built here on the device; the kernel walks the ``n_live`` live slots'
-    entries and fetches nothing past them.  Returns the output's blocks,
-    ``(S, out_cols / bn, bm, bn)``, slot-major as ``a_data`` is: the
-    kernel's runs as they are.  Dead slots' blocks are not written.  One
+    entries and fetches nothing past them.  It holds A as
+    :func:`grouped_a_mode` says from the shapes: the whole stack, or each
+    live slot's row panel fetched once, or each entry's block.  Returns
+    the output's blocks, ``(S, out_cols / bn, bm, bn)``, slot-major as
+    ``a_data`` is: the kernel's runs as they are.  Dead slots' blocks are not written.  One
     compiled program serves every routing.
     """
     return _grouped_spmm(a_data, b_data, members, slot_member, n_live,
@@ -685,11 +754,32 @@ def grouped_stream_spmm(a_data: jax.Array, b_data: jax.Array,
 def _grouped_spmm(a_data, b_data, members, slot_member, n_live, *, out_cols,
                   out_dtype, interpret):
     n_slots = int(slot_member.shape[0])
+    w = members.a_slot.shape[1]
+    ka = a_data.shape[0] // n_slots
+    bm, bn = a_data.shape[1], b_data.shape[2]
+    sched = _grouped_schedule(members, slot_member, n_live, ka=ka,
+                             n_b=b_data.shape[0])
+    mode = grouped_a_mode(n_slots, ka,
+                          bm * a_data.shape[2] * a_data.dtype.itemsize)
+    panel = (ka, w) if mode == "panel" else None
+    runs = _block_runs(a_data, b_data, sched,
+                       chunk=run_chunk(w if panel else n_slots * w),
+                       resident=mode == "resident", out_dtype=out_dtype,
+                       interpret=interpret,
+                       n_live=jnp.asarray(n_live, jnp.int32) * w, panel=panel)
+    return runs.reshape(n_slots, members.n_runs, bm, bn)[:, :out_cols // bn]
+
+
+def _grouped_schedule(members: StreamSchedule, slot_member, n_live, *,
+                     ka: int, n_b: int) -> StreamSchedule:
+    """The work list of :func:`grouped_stream_spmm`: every slot's copy of
+    its member's schedule, slot-major, with slot ``s``'s A blocks at
+    ``s * ka`` and the members' ``n_b`` packed B blocks (the zero block
+    last); its real entries and runs are the ``n_live`` leading slots'."""
+    n_slots = int(slot_member.shape[0])
     n_members, w = members.a_slot.shape
     r = members.n_runs
-    ka = a_data.shape[0] // n_slots
-    wb = (b_data.shape[0] - 1) // n_members
-    bm, bn = a_data.shape[1], b_data.shape[2]
+    wb = (n_b - 1) // n_members
     m = jnp.asarray(slot_member, jnp.int32)
     s = jnp.arange(n_slots, dtype=jnp.int32)[:, None]
     n_live = jnp.asarray(n_live, jnp.int32)
@@ -699,7 +789,7 @@ def _grouped_spmm(a_data, b_data, members, slot_member, n_live, *, out_cols,
 
     b_slot = per_slot(members.b_slot)
     flat = lambda x: x.reshape(-1)  # noqa: E731
-    sched = StreamSchedule(
+    return StreamSchedule(
         flat(s * ka + per_slot(members.a_slot)),
         flat(jnp.where(b_slot < 0, n_members * wb, m[:, None] * wb + b_slot)),
         flat(per_slot(members.cj)),
@@ -708,11 +798,6 @@ def _grouped_spmm(a_data, b_data, members, slot_member, n_live, *, out_cols,
         jnp.zeros(n_slots * r, jnp.int32), jnp.zeros(n_slots * r, jnp.int32),
         n_slots * r, "dest", jnp.reshape(n_live * w, (1,)),
         jnp.reshape(n_live * r, (1,)), jnp.full((1,), -1, jnp.int32))
-    a_nbytes = a_data.size * jnp.dtype(a_data.dtype).itemsize
-    runs = _block_runs(a_data, b_data, sched, chunk=run_chunk(n_slots * w),
-                       resident=a_resident(a_nbytes), out_dtype=out_dtype,
-                       interpret=interpret, n_live=n_live * w)
-    return runs.reshape(n_slots, r, bm, bn)[:, :out_cols // bn]
 
 
 def _panel_kernel(a_slot_ref, b_slot_ref, cj_ref, is_first_ref, is_last_ref,

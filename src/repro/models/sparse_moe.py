@@ -30,12 +30,15 @@ and packs each expert's kept blocks on the device as its dense weights
 are loaded, a few experts at a time.
 
 Observability (:mod:`repro.obs`): ``moe.compress`` spans and the
-``moe.compress_s`` histogram in set-up, gauges ``moe.held_experts`` and
+``moe.compress_s`` histogram in set-up, gauges ``moe.held_experts``,
 ``moe.block_pairs_max`` (the most block pairs one call of the last traced
-layer can run); at run time the named scopes ``moe.route``,
-``moe.dispatch``, ``moe.experts`` (with ``ffn.gate`` / ``ffn.up`` /
-``ffn.act`` / ``ffn.down`` inside), ``moe.combine`` and ``moe.shared``
-(around :func:`repro.models.sparse_linear.sparse_ffn_apply`).  Each call
+layer can run) and ``moe.a_panel_bytes`` (the bytes of one slot's A row
+panel its grouped kernels hold in VMEM, summed over gate, up and down; 0
+when they hold the whole A stack or stream it); at run time the named
+scopes ``moe.route``, ``moe.dispatch``, ``moe.experts`` (with
+``ffn.gate`` / ``ffn.up`` / ``ffn.act`` / ``ffn.down`` inside),
+``moe.combine`` and ``moe.shared`` (around
+:func:`repro.models.sparse_linear.sparse_ffn_apply`).  Each call
 also returns its live rows and executed slots per held expert, a small
 int32 array the caller reads when it likes.
 """
@@ -50,8 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..kernels.stream import StreamSchedule, grouped_stream_spmm, \
-    row_schedules
+from ..kernels.stream import StreamSchedule, grouped_a_mode, \
+    grouped_stream_spmm, row_schedules
 from .sparse_linear import sparse_ffn_apply
 
 __all__ = ["ExpertStack", "RouterSpec", "compress_experts", "rms_norm",
@@ -269,6 +272,10 @@ def sparse_moe_apply(h, w_router, bias, experts: ExpertStack, shared,
     slots = n_slots(t, spec.top_k, e, b)
     obs.get_registry().gauge("moe.block_pairs_max").set(
         slots * experts.pairs_per_slot())
+    blk = b * b * jnp.dtype(jnp.float32).itemsize
+    obs.get_registry().gauge("moe.a_panel_bytes").set(sum(
+        ka * blk for ka in (d // b, d // b, experts.d_ff // b)
+        if grouped_a_mode(slots, ka, blk) == "panel"))
     h = h.astype(jnp.float32)
     with jax.named_scope("moe.route"):
         ids, gates = route(h, w_router, bias, spec)

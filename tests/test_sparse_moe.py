@@ -164,3 +164,40 @@ def test_one_program_serves_every_routing_with_no_host_callback(layer):
     np.testing.assert_array_equal(np.asarray(c1)[0],
                                   np.bincount(np.asarray(ids1)[held],
                                               minlength=HELD))
+
+
+def test_slot_panels_give_the_streamed_answer_bit_for_bit(layer,
+                                                          monkeypatch):
+    """The grouped kernels holding each live slot's A row panel in VMEM
+    run the same dots in the same order as with every A block streamed:
+    the layer's output is the same to the bit.  The VMEM budget is cut so
+    that two panels fit but not the whole stack (panel mode), then so that
+    not even two fit (streamed); the ``moe.a_panel_bytes`` gauge follows."""
+    from repro import obs
+    from repro.kernels import stream
+
+    tokens = 64
+    ka, blk = D // B, B * B * 4
+    slots = n_slots(tokens, SPEC.top_k, HELD, B)
+    shared = _shared(layer, tokens)
+    bias = jnp.zeros(SPEC.n_experts, jnp.float32)
+    h = jax.random.normal(jax.random.key(7), (tokens, D))
+
+    def run(budget, mode):
+        monkeypatch.setattr(stream, "A_RESIDENT_BYTES", budget)
+        stream._grouped_spmm.clear_cache()
+        assert stream.grouped_a_mode(slots, ka, blk) == mode
+        out = jax.jit(lambda h: sparse_moe_apply(
+            h, layer["w_r"], bias, layer["experts"], shared, SPEC))(h)
+        return out, obs.get_registry().get("moe.a_panel_bytes").value
+
+    try:
+        (y_panel, ids, counts), panel_bytes = run(2 * ka * blk, "panel")
+        (y_stream, ids_s, _), stream_bytes = run(2 * ka * blk - 1, "stream")
+    finally:
+        stream._grouped_spmm.clear_cache()
+    assert int(np.asarray(counts)[1].sum()) >= 2     # slots change mid-call
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids_s))
+    np.testing.assert_array_equal(np.asarray(y_panel), np.asarray(y_stream))
+    assert (panel_bytes, stream_bytes) == (3 * ka * blk, 0)
+

@@ -27,6 +27,8 @@ The contracts under test, all in interpret mode on CPU:
   MXU-misaligned blocks surface a typed ``block-alignment`` verify_plan
   diagnostic instead of a Mosaic crash.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -188,10 +190,65 @@ def _runs_in_entry_order(a, b, s, out_dtype):
     return out
 
 
+#: three members' (Ka=3, Nb=4) block masks for grouped calls: member 1
+#: keeps fewer blocks (pad entries) and no block of output column 2 (an
+#: entry against the zero block); 8 entries a slot
+GROUPED_MASKS = np.array([[[1, 0, 1, 1], [1, 1, 0, 0], [0, 1, 1, 1]],
+                          [[1, 1, 0, 0], [0, 1, 0, 1], [0, 0, 0, 1]],
+                          [[1, 1, 1, 1], [1, 0, 1, 0], [0, 1, 0, 1]]], bool)
+
+
+@dataclasses.dataclass(frozen=True)
+class Grouped:
+    """A grouped call: each slot's member, the live slots, entries a grid
+    step (at most a slot's 8, so a step spans at most two slots)."""
+
+    slot_member: tuple
+    n_live: int
+    chunk: int
+    out_dtype: type = jnp.float32
+
+
+def _grouped_operands(case, rng):
+    """A slot-major A stack, the members' packed B blocks (the zero block
+    last) and the grouped work list of ``case``."""
+    from repro.kernels.stream import _grouped_schedule, row_schedules
+
+    members = row_schedules(GROUPED_MASKS)
+    n_members, ka = GROUPED_MASKS.shape[:2]
+    wb = int(GROUPED_MASKS.sum((1, 2)).max())
+    a = jnp.asarray(rng.standard_normal((len(case.slot_member) * ka, 8, 16)),
+                    jnp.float32)
+    b = jnp.asarray(rng.standard_normal((n_members * wb + 1, 16, 8)),
+                    jnp.float32).at[-1].set(0.0)
+    sched = _grouped_schedule(members, jnp.asarray(case.slot_member),
+                              case.n_live, ka=ka, n_b=b.shape[0])
+    return a, b, members, jax.tree.map(np.asarray, sched)
+
+
+def _grouped_product(a, b, slot_member, n_live):
+    """Each live slot's dense row panel times its member's dense weight,
+    rebuilt from the masks and the packed blocks: (n_live, bm, Nb * bn)."""
+    n_members, ka, nb = GROUPED_MASKS.shape
+    wb = (b.shape[0] - 1) // n_members
+    out = []
+    for s, m in enumerate(slot_member[:n_live]):
+        js, ks = np.nonzero(GROUPED_MASKS[m].T)     # the packed order
+        dense = np.zeros((ka, nb) + b.shape[1:], np.float32)
+        dense[ks, js] = np.asarray(b)[m * wb:m * wb + js.size]
+        rows = np.asarray(a)[s * ka:(s + 1) * ka].transpose(1, 0, 2)
+        out.append(rows.reshape(a.shape[1], -1) @
+                   dense.transpose(0, 2, 1, 3).reshape(rows.size // a.shape[1],
+                                                       -1))
+    return np.asarray(out).reshape(n_live, a.shape[1], nb * b.shape[2])
+
+
 #: name -> (run lengths of each stacked member, entries a grid step, out
 #: dtype[, live entries]); one member runs unstacked, two run stacked under
 #: lax.scan; with a live count the kernel stops after that many entries
-#: (on a run boundary) and only the runs before it are compared
+#: (on a run boundary) and only the runs before it are compared.  A
+#: ``Grouped`` case is a grouped call, run with its slots' A row panels
+#: held two at a time as well as with A resident or streamed
 BLOCK_RUN_CASES = {
     "runs-shorter-and-longer-than-chunk": ([[2, 7, 1, 12, 3]], 4,
                                            jnp.float32),
@@ -205,6 +262,11 @@ BLOCK_RUN_CASES = {
     "live-count-at-a-step-edge": ([[4, 4, 3]], 4, jnp.float32, 8),
     "live-count-zero": ([[3, 1, 2]], 2, jnp.float32, 0),
     "live-count-whole-list": ([[5, 6]], 4, jnp.bfloat16, 11),
+    "grouped-no-live-slot": Grouped((0, 1, 2, 0), 0, 3),
+    "grouped-one-live-slot": Grouped((1, 0, 2, 0), 1, 3),
+    "grouped-every-slot-live": Grouped((0, 1, 1, 2), 4, 3),
+    "grouped-chunk-divides-slot": Grouped((2, 1, 0, 0, 1), 3, 4),
+    "grouped-bf16-output": Grouped((1, 2, 0), 3, 5, jnp.bfloat16),
 }
 
 
@@ -219,6 +281,9 @@ def test_block_run_kernel_matches_per_entry_order(case, resident):
     from repro.kernels.stream import (A_RESIDENT_BYTES, _block_runs,
                                       a_resident)
 
+    if isinstance(BLOCK_RUN_CASES[case], Grouped):
+        _check_grouped_panels(BLOCK_RUN_CASES[case], resident)
+        return
     members, chunk, out_dtype, *live = BLOCK_RUN_CASES[case]
     rng = np.random.default_rng(sum(map(len, members)) + chunk)
     a = jnp.asarray(rng.standard_normal((5, 8, 16)), jnp.float32)
@@ -251,6 +316,48 @@ def test_block_run_kernel_matches_per_entry_order(case, resident):
             np.testing.assert_array_equal(g[r], run, err_msg=f"run {r}")
     assert a_resident(A_RESIDENT_BYTES)
     assert not a_resident(A_RESIDENT_BYTES + 1)
+
+
+def _check_grouped_panels(case, resident):
+    """A grouped call with each live slot's A row panel held in VMEM gives
+    the runs of the same call with A resident or streamed, and of a
+    per-entry accumulation, bit for bit, and the plain product of each
+    slot's rows and its member's weight.  The panel run goes through the
+    TPU interpreter, which simulates the kernel's DMAs and semaphores and
+    finds any read or write that races one of them."""
+    from jax._src.pallas.mosaic.interpret import \
+        interpret_pallas_call as tpu_interpret
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.stream import _block_runs
+
+    rng = np.random.default_rng(len(case.slot_member) + case.n_live)
+    a, b, members, sched = _grouped_operands(case, rng)
+    w, (ka, nb) = members.a_slot.shape[1], GROUPED_MASKS.shape[1:]
+    n_live, r = case.n_live, members.n_runs
+
+    def runs(interpret, **mode):
+        got = _block_runs(a, b, sched, chunk=case.chunk,
+                          out_dtype=case.out_dtype, interpret=interpret,
+                          n_live=jnp.int32(n_live * w), **mode)
+        # the live slots' output columns; the pad run is not an output
+        return np.asarray(got).reshape(-1, r, *got.shape[1:])[:n_live, :nb]
+
+    panel = runs(pltpu.InterpretParams(detect_races=True), resident=False,
+                 panel=(ka, w))
+    assert not tpu_interpret.races.races_found
+    other = runs(True, resident=resident)
+    np.testing.assert_array_equal(panel, other)
+    want = _runs_in_entry_order(a, b, sched, case.out_dtype)
+    for s in range(n_live):
+        for j in range(nb):
+            np.testing.assert_array_equal(panel[s, j], want[s * r + j],
+                                          err_msg=f"slot {s} column {j}")
+    assert panel.dtype == np.dtype(case.out_dtype)
+    plain = _grouped_product(a, b, case.slot_member, n_live)
+    got = panel.astype(np.float32).transpose(0, 2, 1, 3).reshape(plain.shape)
+    tol = 1e-5 if case.out_dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(got, plain, rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------

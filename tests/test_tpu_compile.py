@@ -206,25 +206,36 @@ MOE_D, MOE_F, MOE_HELD, MOE_TOKENS = 7168, 2048, 32, 1024
 @pytest.mark.parametrize("d_in,d_out", [(MOE_D, MOE_F), (MOE_F, MOE_D)])
 def test_grouped_kernel_compiles_at_expert_widths(one_chip, d_in, d_out):
     """The grouped block-run kernel for 32 stacked experts and every slot
-    1024 tokens routed top-8 can fill: its ring fits VMEM and it stays one
+    1024 tokens routed top-8 can fill: the slot stack is too large to hold,
+    so it holds two slots' A row panels; its VMEM (those panels and the B
+    ring) stays within a v5e core's 128 MiB, and it stays one
     ``_grouped_spmm`` custom-call."""
-    from repro.kernels.stream import _grouped_spmm, row_schedules
+    import re
+
+    from repro.kernels.stream import (_grouped_spmm, grouped_a_mode,
+                                      row_schedules)
     from repro.models.sparse_moe import n_slots
 
     slots = n_slots(MOE_TOKENS, 8, MOE_HELD, BLOCK)
     sched = row_schedules(_expert_masks(MOE_HELD, d_in // BLOCK,
                                         d_out // BLOCK))
     kept = d_in * d_out // BLOCK ** 2 // 4
+    panel_bytes = d_in // BLOCK * BLOCK * BLOCK * 4
+    assert grouped_a_mode(slots, d_in // BLOCK, BLOCK * BLOCK * 4) == "panel"
 
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    text = _grouped_spmm.lower(
+    lowered = _grouped_spmm.lower(
         sds((slots * d_in // BLOCK, BLOCK, BLOCK), jnp.float32),
         sds((MOE_HELD * kept + 1, BLOCK, BLOCK), jnp.float32),
         jax.tree.map(lambda x: sds(x.shape), sched), sds((slots,)), sds(()),
-        out_cols=d_out, out_dtype=jnp.float32,
-        interpret=False).compile().as_text()
+        out_cols=d_out, out_dtype=jnp.float32, interpret=False)
+    vmem = [int(v) for v in re.findall(
+        r'scoped_memory_configs\\22: \[\{[^}]*\\22size\\22: (\d+)',
+        lowered.as_text())]
+    assert len(vmem) == 1 and 2 * panel_bytes < vmem[0] <= 128 << 20
+    text = lowered.compile().as_text()
     kernels = [line.split("=")[0].strip() for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert slots == 95 and len(kernels) == 1
