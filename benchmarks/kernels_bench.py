@@ -192,8 +192,6 @@ def run(quick: bool = False, verify: bool = False) -> list[Row]:
 
         # selection policies, through the same seam the plans use; each row
         # carries which policy selected and how long its select() takes
-        # ("learned" runs model-less here — heuristic fallback — unless
-        # REPRO_TUNE_MODEL names a fitted artifact; DESIGN.md §16)
         shape = LayerShape(m, k, n, float(occ_a.mean()), float(occ_b.mean()),
                            block=BS)
         ctx = SelectionContext(
@@ -202,7 +200,7 @@ def run(quick: bool = False, verify: bool = False) -> list[Row]:
             spec=TPUSpec(), allowed=allowed_dataflows(
                 get_backend("reference"), BS))
         sel_reps = 5 if quick else 15
-        for pname in ("heuristic", "simulator", "learned"):
+        for pname in ("heuristic", "simulator"):
             pol = get_policy(pname)
             choice = pol.select(ctx)        # warmup (fills policy caches)
             # selection latency as a distribution, not a single draw: the

@@ -43,7 +43,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 ARCH = "qwen2-1.5b"
-SPARSITY = 0.75          # kernel path (work ratio 0.25 < dense_threshold)
+SPARSITY = 0.75          # kernel path (work ratio 0.25 < DENSE_THRESHOLD)
 DENSE_SPARSITY = 0.25    # dense escape hatch (work ratio 0.75)
 DECODE_TOKENS, PREFILL_TOKENS = 4, 512
 KERNEL_TOL = 2e-2        # normalized max error vs the float32 reference

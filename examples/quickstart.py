@@ -68,17 +68,6 @@ def main():
                               policy=get_policy("autotune"))
     print(f"  policy 'autotune'  -> {autotuned.dataflow} "
           "(measured on-device, cached by pattern fingerprint)")
-    # "learned" predicts the simulator's choice in microseconds from cheap
-    # pattern features (repro.tune, DESIGN.md §16).  With no fitted model
-    # artifact (REPRO_TUNE_MODEL unset) it transparently falls back to the
-    # heuristic — fit one with `python -m repro.tune corpus/fit/eval`.
-    learned_pol = get_policy("learned")
-    learned = flexagon_plan(a, b, block_shape=(16, 16, 16),
-                            policy=learned_pol)
-    mode = "fitted model" if learned_pol.model is not None \
-        else "model-less, heuristic fallback"
-    print(f"  policy 'learned'   -> {learned.dataflow} ({mode}; "
-          f"stats {learned_pol.stats})")
     out = np.asarray(plan.apply(a, b))
     print(f"  plan.apply          max|err| = {np.abs(out - oracle).max():.2e}")
     # same pattern, new values — no re-planning, and jit-compatible

@@ -89,7 +89,6 @@ OBS_TIME_ALLOWED = (
     "repro/launch/roofline.py",
     "repro/launch/dryrun.py",
     "repro/launch/train.py",
-    "repro/tune/__main__.py",
 )
 
 

@@ -256,9 +256,9 @@ def _verify_flexagon(plan: FlexagonPlan, diags, loc, *,
     be = _check_backend(plan, diags, loc)
     if be is not None:
         # the same capability negotiation the policy path uses: the plan's
-        # dataflow must be in allowed_dataflows(backend, block_shape), so a
-        # learned/autotuned selection can never commit to a dataflow the
-        # backend would refuse at execution time
+        # dataflow must be in allowed_dataflows(backend, block_shape), so no
+        # policy's selection can commit to a dataflow the backend would
+        # refuse at execution time
         allowed = allowed_dataflows(be, tuple(plan.block_shape))
         if plan.dataflow not in allowed:
             _diag(diags, "backend-unsupported", ERROR,

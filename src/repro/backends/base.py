@@ -155,17 +155,6 @@ class ExecutionBackend(abc.ABC):
         """
         del plans
 
-    def tuning_knobs(self) -> Dict[str, Tuple[Any, ...]]:
-        """Declare this backend's tunable execution knobs.
-
-        Maps attribute name -> candidate values.  ``AutotunePolicy`` sweeps
-        the cross product jointly with the dataflow choice, applies the
-        winning values to the backend instance, and persists them in the
-        shared :class:`repro.tune.TuneDB` so one process's sweep serves the
-        fleet.  Default: no knobs.
-        """
-        return {}
-
     @abc.abstractmethod
     def execute(self, plan, a, b, out_dtype) -> jax.Array:
         """Phase 2: run ``C = A @ B`` for compressed operands ``a``/``b``

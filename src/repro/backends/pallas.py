@@ -6,7 +6,7 @@ All Pallas dispatch lives here (kernels are imported nowhere else outside
 - :meth:`PallasBackend.prepare` lowers the index plan into the kernels'
   phase-1 artifact — a :class:`repro.kernels.StreamSchedule` work list
   (DESIGN.md §18) — once, at plan time.  Tiles whose effectual block-pair
-  count crosses ``dense_threshold`` of the dense work instead take the
+  count reaches :data:`DENSE_THRESHOLD` of the dense work instead take the
   dense escape hatch (FlexiSAGA, arXiv 2506.01566): a plain MXU matmul on
   the densified operands beats sparse machinery at high occupancy;
 - :meth:`PallasBackend.execute` dispatches ``ip_spmm``/``op_spmm``/
@@ -27,7 +27,7 @@ All Pallas dispatch lives here (kernels are imported nowhere else outside
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,13 +37,18 @@ from ..config import resolve_interpret
 from ..core import dataflows as df
 from .base import TABLE3_FORMATS, BackendCapability, ExecutionBackend
 
-__all__ = ["PallasBackend"]
+__all__ = ["DENSE_THRESHOLD", "PallasBackend"]
 
 #: Mosaic tiling for fp32 operands: (sublane, lane) = (8, 128).  Compiled
 #: kernels want every 2-D block's second-minor dim a multiple of 8 and its
 #: minor dim a multiple of 128; interpret mode has no such constraint.
 MXU_SUBLANE = 8
 MXU_LANE = 128
+
+#: occupancy escape hatch: when a plan's effectual block-pair count reaches
+#: this fraction of the dense block-pair count, :meth:`PallasBackend.prepare`
+#: emits a plain dense MXU matmul instead of the sparse kernel.
+DENSE_THRESHOLD = 0.5
 
 
 class PallasBackend(ExecutionBackend):
@@ -58,14 +63,8 @@ class PallasBackend(ExecutionBackend):
     # families over aux["stream_schedule"] before anything executes it
     schedule_aux_key = "stream_schedule"
 
-    def __init__(self, interpret: Optional[bool] = None,
-                 dense_threshold: float = 0.5):
+    def __init__(self, interpret: Optional[bool] = None):
         self.interpret = interpret
-        #: occupancy escape hatch: when a plan's effectual block-pair count
-        #: reaches this fraction of the dense block-pair count, emit a
-        #: plain dense MXU matmul instead of the sparse kernel (>= 1.0
-        #: keeps every plan sparse).  Tunable — see :meth:`tuning_knobs`.
-        self.dense_threshold = float(dense_threshold)
 
     def capabilities(self) -> BackendCapability:
         # All six dataflows (N variants via the transpose duality).  Blocks
@@ -83,10 +82,6 @@ class PallasBackend(ExecutionBackend):
         explicit = plan.interpret if plan.interpret is not None \
             else self.interpret
         return resolve_interpret(explicit)
-
-    def tuning_knobs(self) -> Dict[str, Tuple[Any, ...]]:
-        # 2.0 disables the escape hatch (the ratio never exceeds 1.0)
-        return {"dense_threshold": (0.25, 0.5, 2.0)}
 
     # -- phase 1 ---------------------------------------------------------
     def _work_ratio(self, plan) -> float:
@@ -120,7 +115,7 @@ class PallasBackend(ExecutionBackend):
         else:
             sched = schedule_from_stream(plan.index_plan, by_dest=False)
         aux: Dict[str, Any] = {"stream_schedule": sched}
-        if self._work_ratio(plan) >= self.dense_threshold:
+        if self._work_ratio(plan) >= DENSE_THRESHOLD:
             aux["dense"] = ()
         return aux
 

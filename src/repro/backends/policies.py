@@ -2,19 +2,14 @@
 
 The paper's mapper/compiler estimates every dataflow's cost and picks one.
 Misam (arXiv 2406.10166) shows the *picking* is itself a policy worth
-swapping — heuristic vs. learned vs. measured.  This module is that seam:
+swapping — heuristic vs. simulated vs. measured.  This module is that seam:
 
 - :class:`HeuristicPolicy` — the analytical roofline estimate
   (:func:`repro.core.selector.select_dataflow`), the fast host-side default;
 - :class:`SimulatorPolicy` — pick by simulated cycles on the cycle-level
   accelerator models — the paper's phase 1 proper;
 - :class:`AutotunePolicy`  — measure every candidate dataflow on-device at
-  plan time and pick the fastest, LRU-cached by pattern fingerprint and
-  optionally persisted to a fleet-shared :class:`repro.tune.TuneDB` (plan
-  once, measure once — anywhere — reuse forever);
-- :class:`repro.tune.LearnedPolicy` (``policy="learned"``) — predict the
-  choice in microseconds from cheap pattern features, falling back to the
-  heuristic below a confidence threshold (DESIGN.md §16);
+  plan time and pick the fastest, LRU-cached by pattern fingerprint;
 - :class:`FixedPolicy`     — always the given dataflow (what an explicit
   ``dataflow="ip_m"`` argument resolves to).
 
@@ -29,8 +24,6 @@ from __future__ import annotations
 import abc
 import dataclasses
 import hashlib
-import itertools
-import os
 import time
 from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple, Union
@@ -111,8 +104,7 @@ class SelectionPolicy(abc.ABC):
         """Telemetry counters (surfaced as ``ServeEngine.stats["policy"]``).
 
         Stateful policies extend with their own counters: autotune's
-        hit/miss/measurement counts, the learned policy's
-        selection/fallback counts.
+        hit/miss/measurement counts.
         """
         return {"name": self.name}
 
@@ -158,10 +150,9 @@ class SelectionPolicy(abc.ABC):
         planning, where the routing pattern only exists at run time.
 
         The fingerprint carries the block shape and value dtype alongside
-        ``m×k×n`` + densities: shape-only selections are cached (and, with
-        a persistent :class:`repro.tune.TuneDB`, shared across the fleet)
-        by this string, and the same logical shape at two block configs or
-        element widths measures differently — the keys must not collide.
+        ``m×k×n`` + densities: shape-only selections are cached by this
+        string, and the same logical shape at two block configs or element
+        widths measures differently — the keys must not collide.
         """
         be = get_backend(backend)
         bm, bk, bn = shape.block
@@ -235,9 +226,8 @@ class SimulatorPolicy(SelectionPolicy):
     def price(self, ctx: SelectionContext) -> Dict[str, float]:
         """Simulated time per allowed dataflow — ``select`` is its argmin.
 
-        Exposed so callers that need the full cost vector (margin-aware
-        corpus labeling in :mod:`repro.tune.corpus`, diagnostics) don't
-        re-price candidates one ``layer_cost`` call at a time.
+        Exposed so callers that need the full cost vector (diagnostics)
+        don't re-price candidates one ``layer_cost`` call at a time.
         """
         sim = self._oracle()
         shards = ctx.n_shards
@@ -286,52 +276,28 @@ class AutotunePolicy(SelectionPolicy):
     *target* backend, times ``plan.apply`` wall-clock, and picks the fastest.
     Results are cached by ``(fingerprint, backend, block_shape, budget,
     mesh, partition)`` so a serving loop pays the sweep once per pattern —
-    and repeat selections are deterministic by construction.
+    and repeat selections are deterministic by construction.  The policy
+    only reads ``ctx.backend``: the plans it measures run the backend as
+    every other plan does.
 
-    The in-memory cache is **LRU-bounded** (``maxsize``): under shifting
-    serving traffic an unbounded dict grows forever.  ``hits`` / ``misses``
-    / ``measurements`` / ``evictions`` counters mirror the ``PlanCache``
+    The cache is **LRU-bounded** (``maxsize``): under shifting serving
+    traffic an unbounded dict grows forever.  ``hits`` / ``misses`` /
+    ``measurements`` / ``evictions`` counters mirror the ``PlanCache``
     telemetry and surface through ``ServeEngine.stats["policy"]``.
-
-    ``db=`` (a path or :class:`repro.tune.TuneDB`; defaults to the
-    ``REPRO_TUNE_DB`` env var when unset) makes the measurement
-    cache **persistent and fleet-shared**: selects read through the
-    on-disk database before measuring and write every fresh sweep back, so
-    a second process (or a restarted server) starts hot — its first select
-    on a known pattern is a cold-start disk hit, not a sweep
-    (``db_hits``; asserted in tests/test_tune.py).
-
-    Backends may declare **tuning knobs**
-    (:meth:`repro.backends.ExecutionBackend.tuning_knobs`, e.g. the pallas
-    dense-escape threshold): the sweep then measures the (dataflow × knob)
-    cross product jointly, applies the winning knob values to the backend
-    instance before the real plan is built, and persists them alongside
-    the choice — a DB hit in another process re-applies them without
-    measuring.  :meth:`select_block` runs the same measure-once-share-
-    everywhere discipline over candidate kernel *block shapes*.
     """
 
     name = "autotune"
 
-    def __init__(self, reps: int = 2, maxsize: Optional[int] = 1024,
-                 db: Optional[Any] = None):
+    def __init__(self, reps: int = 2, maxsize: Optional[int] = 1024):
         if maxsize is not None and maxsize < 1:
             raise ValueError(f"maxsize must be >= 1 or None, got {maxsize}")
         self.reps = reps
         self.maxsize = maxsize
-        self._cache: "OrderedDict[tuple, Any]" = OrderedDict()
+        self._cache: "OrderedDict[tuple, str]" = OrderedDict()
         self.measurements = 0      # sweep count, for tests/telemetry
-        self.hits = 0              # in-memory LRU hits
+        self.hits = 0              # LRU hits
         self.misses = 0
         self.evictions = 0
-        self.db_hits = 0           # persistent-DB read-through hits
-        if db is None:
-            db = os.environ.get("REPRO_TUNE_DB") or None
-        if db is not None and not hasattr(db, "get"):
-            from ..tune.db import TuneDB   # lazy: tune imports this module
-
-            db = TuneDB(str(db))
-        self.db = db
 
     @property
     def stats(self) -> Dict[str, Any]:
@@ -340,31 +306,7 @@ class AutotunePolicy(SelectionPolicy):
                     "measurements": self.measurements,
                     "evictions": self.evictions,
                     "size": len(self._cache), "maxsize": self.maxsize})
-        if self.db is not None:
-            out["db_hits"] = self.db_hits
-            out["db"] = self.db.stats
         return out
-
-    def _db_key(self, ctx: SelectionContext) -> str:
-        from ..dist.partition import mesh_key   # lazy: dist uses api
-        from ..tune.db import db_key            # lazy: tune imports us
-
-        return db_key(ctx.fingerprint, ctx.backend.name, ctx.block_shape,
-                      memory_budget=ctx.memory_budget,
-                      mesh_key=mesh_key(ctx.mesh), partition=ctx.partition,
-                      accel=getattr(ctx.backend, "cfg", None))
-
-    def _remember(self, key: tuple, value: Any) -> None:
-        self._cache[key] = value
-        self._cache.move_to_end(key)
-        if self.maxsize is not None and len(self._cache) > self.maxsize:
-            self._cache.popitem(last=False)
-            self.evictions += 1
-
-    @staticmethod
-    def _apply_knobs(backend, knobs: Dict[str, Any]) -> None:
-        for attr, value in (knobs or {}).items():
-            setattr(backend, attr, value)
 
     def select(self, ctx: SelectionContext) -> str:
         from ..dist.partition import mesh_key   # lazy: dist uses api
@@ -372,33 +314,17 @@ class AutotunePolicy(SelectionPolicy):
         key = (ctx.fingerprint, ctx.backend.name, ctx.block_shape,
                ctx.memory_budget, mesh_key(ctx.mesh), ctx.partition)
         hit = self._cache.get(key)
-        if hit is not None and hit[0] in ctx.allowed:
+        if hit is not None and hit in ctx.allowed:
             self.hits += 1
             self._cache.move_to_end(key)
-            self._apply_knobs(ctx.backend, hit[1])
-            return hit[0]
+            return hit
         self.misses += 1
-        if self.db is not None:
-            rec = self.db.get(self._db_key(ctx))
-            if rec is not None and rec.get("choice") in ctx.allowed:
-                self.db_hits += 1
-                knobs = dict(rec.get("knobs") or {})
-                self._remember(key, (rec["choice"], knobs))
-                self._apply_knobs(ctx.backend, knobs)
-                return rec["choice"]
-        choice, knobs, timings = self._measure(ctx)
-        self._remember(key, (choice, knobs))
-        if self.db is not None:
-            self.db.put(self._db_key(ctx), {
-                "choice": choice,
-                "knobs": knobs,
-                "timings_s": timings,
-                "fingerprint": ctx.fingerprint,
-                "backend": ctx.backend.name,
-                "block_shape": list(ctx.block_shape),
-                "reps": self.reps,
-            })
-        self._apply_knobs(ctx.backend, knobs)
+        choice = self._measure(ctx)
+        self._cache[key] = choice
+        self._cache.move_to_end(key)
+        if self.maxsize is not None and len(self._cache) > self.maxsize:
+            self._cache.popitem(last=False)
+            self.evictions += 1
         return choice
 
     def _synth_operands(self, ctx: SelectionContext):
@@ -421,114 +347,28 @@ class AutotunePolicy(SelectionPolicy):
             best = min(best, time.perf_counter() - t0)  # lint: time-ok
         return best
 
-    def _measure(self, ctx: SelectionContext
-                 ) -> Tuple[str, Dict[str, Any], Dict[str, float]]:
+    def _measure(self, ctx: SelectionContext) -> str:
         from .. import obs
         from ..api import flexagon_plan  # lazy: api imports this module
 
         self.measurements += 1
         obs.get_registry().counter("policy.measurements").inc()
         a, b = self._synth_operands(ctx)
-        # joint (dataflow x backend-knob) sweep: backends with declared
-        # tuning knobs get each knob combination measured per dataflow
-        knob_space = getattr(ctx.backend, "tuning_knobs", dict)() or {}
-        names = sorted(knob_space)
-        combos = [dict(zip(names, vals))
-                  for vals in itertools.product(*(knob_space[nm]
-                                                  for nm in names))] or [{}]
-        saved = {nm: getattr(ctx.backend, nm) for nm in names}
         timings: Dict[str, float] = {}
-        scored: Dict[Tuple[str, int], float] = {}
-        try:
-            for ci, combo in enumerate(combos):
-                self._apply_knobs(ctx.backend, combo)
-                tag = ",".join(f"{nm}={combo[nm]}" for nm in names)
-                for d in ctx.allowed:
-                    # with a memory budget (or a mesh) the throwaway plan
-                    # tiles and shards exactly like the real one, so the
-                    # measurement *is* the tiled / sharded execution
-                    with obs.span("policy.autotune.measure", dataflow=d,
-                                  reps=self.reps) as sp:
-                        plan = flexagon_plan(
-                            a, b, dataflow=d, block_shape=ctx.block_shape,
-                            spec=ctx.spec, backend=ctx.backend,
-                            memory_budget=ctx.memory_budget,
-                            mesh=ctx.mesh, partition=ctx.partition)
-                        best = self._time_plan(plan, a, b)
-                        scored[(d, ci)] = best
-                        timings[f"{d}|{tag}" if tag else d] = best
-                        sp.set(best_s=best)
-        finally:
-            self._apply_knobs(ctx.backend, saved)
-        choice, ci = min(scored, key=lambda dc: (scored[dc], dc))
-        return choice, combos[ci], timings
-
-    def select_block(self, ctx: SelectionContext,
-                     candidates: Tuple[Tuple[int, int, int], ...]
-                     ) -> Tuple[int, int, int]:
-        """Measure candidate kernel block shapes for this pattern.
-
-        The block-shape analogue of :meth:`select`: synthesizes values on
-        the pattern, builds one (policy-default dataflow) plan per
-        candidate block shape on the target backend, times ``apply``, and
-        returns the fastest — cached in the same LRU and persisted under a
-        ``block:``-prefixed TuneDB key so the sweep runs once per
-        fingerprint across processes.
-        """
-        from .. import obs
-        from ..api import flexagon_plan  # lazy: api imports this module
-        from ..dist.partition import mesh_key   # lazy: dist uses api
-        from ..tune.db import db_key            # lazy: tune imports us
-
-        candidates = tuple(tuple(c) for c in candidates)
-        if not candidates:
-            raise ValueError("select_block needs at least one candidate")
-        key = ("block", ctx.fingerprint, ctx.backend.name, candidates,
-               ctx.memory_budget, mesh_key(ctx.mesh), ctx.partition)
-        hit = self._cache.get(key)
-        if hit is not None:
-            self.hits += 1
-            self._cache.move_to_end(key)
-            return hit
-        self.misses += 1
-        dbk = db_key(f"block:{ctx.fingerprint}", ctx.backend.name,
-                     ctx.block_shape, memory_budget=ctx.memory_budget,
-                     mesh_key=mesh_key(ctx.mesh), partition=ctx.partition,
-                     accel=getattr(ctx.backend, "cfg", None))
-        if self.db is not None:
-            rec = self.db.get(dbk)
-            best = tuple(rec["block_shape"]) if rec else None
-            if best in candidates:
-                self.db_hits += 1
-                self._remember(key, best)
-                return best
-        self.measurements += 1
-        obs.get_registry().counter("policy.measurements").inc()
-        a, b = self._synth_operands(ctx)
-        timings: Dict[str, float] = {}
-        for cand in candidates:
-            with obs.span("policy.autotune.measure_block",
-                          block=str(cand), reps=self.reps) as sp:
-                plan = flexagon_plan(a, b, block_shape=cand, spec=ctx.spec,
-                                     backend=ctx.backend,
-                                     memory_budget=ctx.memory_budget,
-                                     mesh=ctx.mesh, partition=ctx.partition)
-                t = self._time_plan(plan, a, b)
-                timings["x".join(map(str, cand))] = t
-                sp.set(best_s=t)
-        best = min(candidates,
-                   key=lambda c: (timings["x".join(map(str, c))], c))
-        self._remember(key, best)
-        if self.db is not None:
-            self.db.put(dbk, {
-                "choice": "x".join(map(str, best)),
-                "block_shape": list(best),
-                "timings_s": timings,
-                "fingerprint": ctx.fingerprint,
-                "backend": ctx.backend.name,
-                "reps": self.reps,
-            })
-        return best
+        for d in ctx.allowed:
+            # with a memory budget (or a mesh) the throwaway plan tiles and
+            # shards exactly like the real one, so the measurement *is* the
+            # tiled / sharded execution
+            with obs.span("policy.autotune.measure", dataflow=d,
+                          reps=self.reps) as sp:
+                plan = flexagon_plan(
+                    a, b, dataflow=d, block_shape=ctx.block_shape,
+                    spec=ctx.spec, backend=ctx.backend,
+                    memory_budget=ctx.memory_budget,
+                    mesh=ctx.mesh, partition=ctx.partition)
+                timings[d] = self._time_plan(plan, a, b)
+                sp.set(best_s=timings[d])
+        return min(timings, key=lambda d: (timings[d], d))
 
     def layer_cost(self, shape: LayerShape, dataflow: str,
                    spec: Optional[TPUSpec] = None,
@@ -596,15 +436,9 @@ def get_policy(policy: Union[str, SelectionPolicy, None],
     - ``dataflow="mixed"`` is *not* a pin: per-tile choices still need a
       pricing policy, so ``policy`` resolves exactly as it would for
       "auto" and the mixed planner calls its ``select_tile`` per tile;
-    - ``policy`` may be a name ("heuristic" / "simulator" / "autotune" /
-      "learned" — or a dataflow name, shorthand for a fixed pin) or an
-      instance;
+    - ``policy`` may be a name ("heuristic" / "simulator" / "autotune" —
+      or a dataflow name, shorthand for a fixed pin) or an instance;
     - neither given → :class:`HeuristicPolicy`.
-
-    ``"learned"`` resolves to :class:`repro.tune.LearnedPolicy`: if
-    ``REPRO_TUNE_MODEL`` names a fitted artifact it is loaded once; with
-    no artifact the policy is model-less and transparently falls back to
-    the heuristic on every select (counted in its ``stats``).
     """
     if dataflow not in ("auto", "mixed"):
         return FixedPolicy(dataflow)
@@ -614,20 +448,14 @@ def get_policy(policy: Union[str, SelectionPolicy, None],
         return policy
     if policy in df.DATAFLOWS:
         return FixedPolicy(policy)
-    if policy not in ("heuristic", "simulator", "autotune", "learned"):
+    if policy not in ("heuristic", "simulator", "autotune"):
         raise KeyError(f"unknown policy {policy!r}; expected "
-                       "'heuristic', 'simulator', 'autotune', 'learned', "
+                       "'heuristic', 'simulator', 'autotune', "
                        "a dataflow name, or a SelectionPolicy instance")
     inst = _NAMED.get(policy)
     if inst is None:
-        if policy == "learned":
-            from ..tune.learned import LearnedPolicy   # lazy: tune uses us
-
-            path = os.environ.get("REPRO_TUNE_MODEL")
-            inst = LearnedPolicy.load(path) if path else LearnedPolicy()
-        else:
-            inst = {"heuristic": HeuristicPolicy,
-                    "simulator": SimulatorPolicy,
-                    "autotune": AutotunePolicy}[policy]()
+        inst = {"heuristic": HeuristicPolicy,
+                "simulator": SimulatorPolicy,
+                "autotune": AutotunePolicy}[policy]()
         _NAMED[policy] = inst
     return inst

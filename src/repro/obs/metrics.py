@@ -11,7 +11,7 @@ namespace       examples
 ``plan.*``      ``plan.builds``, ``plan.build_s`` (histogram)
 ``cache.*``     ``cache.hits``, ``cache.misses``, ``cache.evictions``
 ``policy.*``    ``policy.select_s``, ``policy.select_tile_s``,
-                ``policy.measurements``, ``policy.learned_fallbacks``
+                ``policy.measurements``
 ``serve.*``     ``serve.prefills``, ``serve.latency.decode_step_s``
 ``dist.*``      ``dist.ici_bytes``
 ``ffn.*``       ``ffn.block_pairs`` (gauge), ``ffn.mask_s``, ``ffn.pack_s``

@@ -317,7 +317,7 @@ def span(name: str, **attrs: Any):
 
 
 def traced(name: Optional[str] = None, **attrs: Any):
-    """Decorator form: ``@traced("tune.fit")`` or bare ``@traced()``."""
+    """Decorator form: ``@traced("plan.build")`` or bare ``@traced()``."""
     import functools
 
     def deco(fn):

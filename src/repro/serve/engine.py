@@ -190,7 +190,7 @@ class ServeEngine:
             if cache_stats is not None:
                 ps["plan_cache"] = copy.deepcopy(cache_stats)
             # selection-policy telemetry (autotune hit/miss/measurement
-            # counters, learned fallback counts — DESIGN.md §16)
+            # counters — DESIGN.md §11)
             pol = getattr(self.sparse_ffn, "policy", None)
             if pol is not None:
                 pol_stats = getattr(pol, "stats", None)

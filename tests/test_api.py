@@ -4,11 +4,16 @@ The contract under test (DESIGN.md §2): ``flexagon_plan`` does ALL host-side
 work — occupancy, selector, compression layouts, index plans — exactly once;
 ``plan.apply`` is pure jnp, jit-compatible, and never re-plans.
 """
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro
 import repro.api as api
 from repro import (FlexagonPipeline, FlexagonPlan, PlanCache, SparseFormat,
                    SparseOperand, flexagon_plan)
@@ -257,3 +262,30 @@ def test_pipeline_rejects_mismatched_chain():
           random_sparse_dense(rng, (16, 8), density=0.5, block_shape=(8, 8))]
     with pytest.raises(ValueError, match="previous layer"):
         FlexagonPipeline.from_weights(ws, tokens=8, block_shape=BS)
+
+
+def test_pattern_fingerprint_stable_cross_process():
+    """The pattern fingerprint (occupancy + shapes + block shape) must
+    re-derive byte-identically in a fresh interpreter: it keys
+    ``PlanCache`` and autotune's measurement cache, so instability would
+    re-plan and re-measure patterns that were already seen."""
+    from repro.api import _fingerprint
+
+    rng = np.random.default_rng(0)
+    occ_a = rng.random((7, 5)) < 0.4
+    occ_b = rng.random((5, 9)) < 0.7
+    local = _fingerprint(occ_a, occ_b, (112, 80, 144), (16, 16, 16))
+    child = (
+        "import numpy as np\n"
+        "from repro.api import _fingerprint\n"
+        "rng = np.random.default_rng(0)\n"
+        "occ_a = rng.random((7, 5)) < 0.4\n"
+        "occ_b = rng.random((5, 9)) < 0.7\n"
+        "print(_fingerprint(occ_a, occ_b, (112, 80, 144), (16, 16, 16)))\n"
+    )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(repro.__file__))
+    env["PYTHONHASHSEED"] = "999"
+    proc = subprocess.run([sys.executable, "-c", child], check=True,
+                          capture_output=True, text=True, env=env)
+    assert proc.stdout.strip() == local

@@ -7,9 +7,12 @@ Contracts under test (DESIGN.md §11):
 - capability negotiation: every default backend declares all six dataflows;
 - parity: all six dataflows × reference/pallas agree numerically on shared
   patterns (same plan, re-targeted with ``with_backend``);
-- policies: Heuristic matches ``select_dataflow``; Simulator/Autotune return
-  a legal dataflow deterministically for a fixed fingerprint (autotune
-  measures once per fingerprint); Fixed pins;
+- policies: Heuristic matches ``select_dataflow`` and picks today's
+  dataflow at every benchmark matmul shape; Simulator/Autotune return a
+  legal dataflow deterministically for a fixed fingerprint (autotune
+  measures once per fingerprint, in a bounded LRU, and leaves the backend
+  as it found it); Fixed pins; ``get_policy`` resolves the three names,
+  the six dataflow pins and nothing else;
 - phase-1-once: ``plan.apply`` on the pallas backend leaves
   ``PHASE1_COUNTERS`` untouched;
 - the interpret default centralizes in ``repro.config`` and follows the
@@ -24,8 +27,9 @@ import repro.api as api
 from repro import flexagon_plan, get_backend, get_policy
 from repro.backends import (AutotunePolicy, BackendCapability,
                             ExecutionBackend, FixedPolicy, HeuristicPolicy,
-                            SimulatorPolicy, TABLE3_FORMATS,
-                            available_backends, register_backend)
+                            SelectionContext, SimulatorPolicy, TABLE3_FORMATS,
+                            allowed_dataflows, available_backends,
+                            register_backend)
 from repro.config import interpret_default, resolve_interpret
 from repro.core import dataflows as df
 from repro.core.formats import random_sparse_dense
@@ -39,6 +43,25 @@ def _case(seed=0, m=24, k=40, n=32, da=0.4, db=0.6):
     a = random_sparse_dense(rng, (m, k), density=da, block_shape=(8, 8))
     b = random_sparse_dense(rng, (k, n), density=db, block_shape=(8, 8))
     return a, b
+
+
+def _context(m=64, k=64, n=96, da=0.5, db=0.6, seed=0, budget=None,
+             allowed=None, backend="reference", bs=(16, 16, 16)):
+    """One SelectionContext on a seeded random block pattern."""
+    be = get_backend(backend)
+    rng = np.random.default_rng(seed)
+    bm, bk, bn = bs
+    occ_a = rng.random((m // bm, k // bk)) < da
+    occ_b = rng.random((k // bk, n // bn)) < db
+    occ_a[0, 0] = occ_b[0, 0] = True          # never a fully-empty operand
+    shape = LayerShape(m, k, n, float(occ_a.mean()), float(occ_b.mean()),
+                       block=bs)
+    return SelectionContext(
+        shape=shape, block_shape=bs, occ_a=occ_a, occ_b=occ_b,
+        fingerprint=f"test:{m}x{k}x{n}:{da}:{db}:{seed}",
+        backend=be, spec=TPUSpec(),
+        allowed=tuple(allowed) if allowed else allowed_dataflows(be, bs),
+        memory_budget=budget)
 
 
 # ---------------------------------------------------------------------------
@@ -182,6 +205,34 @@ def test_heuristic_policy_matches_selector():
     assert plan.dataflow == select_dataflow(shape, TPUSpec())
 
 
+#: the benchmark cells' matmuls, tokens x d x ff (gate and up) and tokens x
+#: ff x d (down), at 128-blocks with a dense A and 25% of B's blocks kept
+_CELL_FAMILIES = [(16, 4096, 14336), (1024, 4096, 14336), (32, 8192, 22016),
+                  (1024, 7168, 18432), (1024, 7168, 2048)]
+_CELL_MATMULS = [mkn for m, d, f in _CELL_FAMILIES
+                 for mkn in ((m, d, f), (m, f, d))]
+
+
+@pytest.mark.parametrize("m,k,n", _CELL_MATMULS)
+def test_heuristic_choice_at_benchmark_shapes(m, k, n):
+    """Pins the dataflow each benchmark matmul runs: the DeepSeek-V3 dense
+    layer's gate and up take ``ip_n``, every other matmul ``ip_m``."""
+    block = (128, 128, 128)
+    shape = LayerShape(m=m, k=k, n=n, density_a=1.0, density_b=0.25,
+                       block=block)
+    want = "ip_n" if (m, k, n) == (1024, 7168, 18432) else "ip_m"
+    assert select_dataflow(shape) == want
+    be = get_backend("pallas")
+    mb, kb, nb = shape.grid
+    occ_b = np.zeros((kb, nb), dtype=bool)
+    occ_b.flat[::4] = True
+    ctx = SelectionContext(
+        shape=shape, block_shape=block, occ_a=np.ones((mb, kb), dtype=bool),
+        occ_b=occ_b, fingerprint=f"cell:{m}x{k}x{n}", backend=be,
+        spec=TPUSpec(), allowed=allowed_dataflows(be, block))
+    assert HeuristicPolicy().select(ctx) == want
+
+
 def test_fixed_policy_pins():
     a, b = _case(seed=8)
     plan = flexagon_plan(a, b, block_shape=BS, policy=FixedPolicy("op_n"))
@@ -214,6 +265,79 @@ def test_autotune_policy_caches_by_fingerprint():
     a2, _ = _case(seed=11, m=16, k=16, n=16, da=0.9)
     flexagon_plan(a2, b, block_shape=BS, policy=pol)
     assert pol.measurements == 2
+
+
+def test_autotune_lru_bounded_and_counted():
+    pol = AutotunePolicy(reps=1, maxsize=2)
+    ctxs = [_context(m=32, k=32, n=32, seed=s, allowed=("ip_m", "gust_m"))
+            for s in range(3)]
+    for ctx in ctxs:
+        pol.select(ctx)
+    assert pol.measurements == 3 and pol.misses == 3
+    assert pol.evictions == 1 and pol.stats["size"] == 2
+    pol.select(ctxs[2])                       # still resident
+    assert pol.hits == 1 and pol.measurements == 3
+    pol.select(ctxs[0])                       # evicted: re-measured
+    assert pol.measurements == 4
+    stats = pol.stats
+    assert stats["name"] == "autotune" and stats["maxsize"] == 2
+    assert {"hits", "misses", "measurements", "evictions"} <= stats.keys()
+
+
+def test_autotune_maxsize_validation():
+    with pytest.raises(ValueError):
+        AutotunePolicy(maxsize=0)
+    AutotunePolicy(maxsize=None)              # unbounded is explicit, fine
+
+
+def test_select_for_shape_fingerprint_block_and_dtype():
+    """The shape-only fingerprint must split on block shape and dtype:
+    the same logical shape at two element widths measures differently."""
+    pol = AutotunePolicy(reps=1)
+    s16 = LayerShape(32, 32, 32, 1.0, 1.0, block=(16, 16, 16))
+    pol.select_for_shape(s16)
+    pol.select_for_shape(s16)                       # cache hit
+    assert pol.measurements == 1 and pol.hits == 1
+    pol.select_for_shape(s16, dtype="bfloat16")     # new key: dtype
+    assert pol.measurements == 2
+    s32 = LayerShape(32, 32, 32, 1.0, 1.0, block=(32, 32, 32))
+    pol.select_for_shape(s32)                       # new key: block shape
+    assert pol.measurements == 3
+
+
+def test_autotune_leaves_the_backend_untouched():
+    """Measuring runs the registered pallas backend as every other plan
+    does: the attributes it finds are the attributes it leaves, so a
+    heuristic plan built after the select escapes to dense exactly as one
+    built before it."""
+    be = get_backend("pallas")
+    before = dict(vars(be))
+    # work ratio 0.35: sparse at the module threshold, dense at a lower one
+    a2, b2 = _case(seed=14, m=16, k=40, n=32, da=1.0, db=0.4)
+    keys_before = set(flexagon_plan(a2, b2, block_shape=BS,
+                                    backend="pallas").aux)
+    ctx = _context(m=16, k=16, n=16, seed=15, backend="pallas", bs=BS)
+    assert AutotunePolicy(reps=1).select(ctx) in ctx.allowed
+    assert vars(be) == before
+    keys_after = set(flexagon_plan(a2, b2, block_shape=BS,
+                                   backend="pallas").aux)
+    assert keys_after == keys_before
+
+
+@pytest.mark.parametrize("name", [None, "heuristic", "simulator", "autotune",
+                                  *df.DATAFLOWS, "learned"])
+def test_get_policy_resolves(name):
+    """The three named policies, a fixed pin per dataflow, nothing else."""
+    named = {None: HeuristicPolicy, "heuristic": HeuristicPolicy,
+             "simulator": SimulatorPolicy, "autotune": AutotunePolicy}
+    if name in named:
+        assert type(get_policy(name)) is named[name]
+    elif name in df.DATAFLOWS:
+        pol = get_policy(name)
+        assert isinstance(pol, FixedPolicy) and pol.dataflow == name
+    else:
+        with pytest.raises(KeyError, match="unknown policy"):
+            get_policy(name)
 
 
 def test_named_policies_are_singletons():

@@ -269,3 +269,26 @@ def test_verify_plans_audits_live_cache(model_and_params):
 
     no_ffn = ServeEngine(model, params, slots=1, max_seq=16)
     assert no_ffn.verify_plans() == []
+
+
+def test_engine_surfaces_policy_stats():
+    from repro.backends import AutotunePolicy
+    from repro.configs.base import ModelConfig
+    from repro.models.ffn import ffn_init
+    from repro.models.sparse_linear import compress_ffn
+
+    cfg = get_config("smollm-360m", smoke=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    fcfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=64,
+                       n_heads=4, d_ff=96, vocab=64, ffn_block_sparsity=0.4)
+    fparams = ffn_init(jax.random.PRNGKey(0), fcfg)
+    fparams["block_mask"] = (jax.random.uniform(
+        jax.random.PRNGKey(9), (4, 6)) > 0.4).astype(jnp.float32)
+    pol = AutotunePolicy(reps=1, maxsize=8)
+    comp = compress_ffn(fparams, tokens=2, block=16, policy=pol)
+    eng = ServeEngine(model, params, slots=2, max_seq=64, sparse_ffn=comp)
+    stats = eng.stats["policy"]
+    assert stats["name"] == "autotune"
+    assert stats["measurements"] == pol.measurements >= 1
+    assert {"hits", "misses", "evictions", "size", "maxsize"} <= stats.keys()

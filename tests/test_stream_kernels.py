@@ -11,18 +11,16 @@ The contracts under test, all in interpret mode on CPU:
   on a virtual mesh and matches the dense reference;
 - **mixed fused lanes** — ``dataflow="mixed"`` groups same-shape tiles
   into lanes; a pallas lane scans as one fused call and stays correct;
-- **dense escape** — high-occupancy plans take the plain-MXU matmul hatch
-  (``"dense"`` aux marker), the ``dense_threshold`` knob moves the
-  boundary, and numerics are unchanged either way;
+- **dense escape** — plans whose work ratio reaches ``DENSE_THRESHOLD``
+  take the plain-MXU matmul hatch (``"dense"`` aux marker) in every
+  dataflow, plans just under it stay sparse, and numerics are unchanged
+  either way;
 - **block-run kernel** — the chunked grid with its operand DMA ring and
   resident A gives the runs of a per-entry accumulation bit for bit:
   runs shorter and longer than a chunk, ragged last chunks, stacked
   schedules under ``lax.scan``;
 - **schedule padding** — ``pad_schedule``'s self-contained pad runs target
   a dropped out-of-bounds row and reject impossible extents;
-- **block autotuning** — backends expose ``tuning_knobs`` and
-  ``AutotunePolicy.select_block`` sweeps block shapes with TuneDB
-  persistence;
 - **alignment diagnostic** — compiled (interpret=False) plans with
   MXU-misaligned blocks surface a typed ``block-alignment`` verify_plan
   diagnostic instead of a Mosaic crash.
@@ -36,11 +34,10 @@ import pytest
 
 from repro import MemoryBudget, ShardedPlan, TiledPlan, flexagon_plan
 from repro.analysis import verify_plan
-from repro.backends import SelectionContext, allowed_dataflows, get_backend
-from repro.backends.policies import AutotunePolicy
+from repro.backends import get_backend
+from repro.backends.pallas import DENSE_THRESHOLD
 from repro.core import random_sparse_dense
 from repro.core.dataflows import DATAFLOWS
-from repro.core.selector import LayerShape, TPUSpec
 from repro.kernels import StreamSchedule, pad_schedule, schedule_from_ip
 from repro.launch.mesh import make_virtual_mesh
 
@@ -139,20 +136,31 @@ def test_dense_escape_marker_and_parity():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_dense_threshold_knob_moves_the_boundary():
-    from repro.backends.pallas import PallasBackend
+def _ratio_case(kept, seed=6):
+    """Dense A (2 x 4 blocks) times a B with ``kept`` of its 4 x 4 blocks:
+    the plan's work ratio is exactly ``kept / 16`` in every dataflow."""
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal((16, 32)).astype(np.float32)
+    b = np.zeros((32, 32), np.float32)
+    for blk in rng.permutation(16)[:kept]:
+        r, c = divmod(int(blk), 4)
+        b[r * 8:(r + 1) * 8, c * 8:(c + 1) * 8] = \
+            rng.standard_normal((8, 8)).astype(np.float32) + 0.1
+    return a, b
 
-    rng = np.random.default_rng(6)
-    a = random_sparse_dense(rng, (32, 32), density=0.95, block_shape=BS[:2])
-    b = random_sparse_dense(rng, (32, 32), density=0.95, block_shape=BS[1:])
-    off = PallasBackend(dense_threshold=2.0)   # ratio never reaches 2.0
-    off.name = "pallas-dense-off"
-    plan = flexagon_plan(a, b, dataflow="ip_m", block_shape=BS, backend=off)
-    assert "dense" not in plan.aux
+
+@pytest.mark.parametrize("kept", [7, 8], ids=["under", "at"])
+@pytest.mark.parametrize("dataflow", DATAFLOWS)
+def test_dense_escape_boundary(dataflow, kept):
+    a, b = _ratio_case(kept)
+    plan = flexagon_plan(a, b, dataflow=dataflow, block_shape=BS,
+                         backend="pallas")
+    ratio = get_backend("pallas")._work_ratio(plan)
+    assert ratio == kept / 16
+    assert ("dense" in plan.aux) == (ratio >= DENSE_THRESHOLD)
     assert "stream_schedule" in plan.aux
     np.testing.assert_allclose(np.asarray(plan.apply(a, b)), a @ b,
                                rtol=1e-4, atol=1e-4)
-    assert "dense_threshold" in get_backend("pallas").tuning_knobs()
 
 
 # ---------------------------------------------------------------------------
@@ -385,60 +393,6 @@ def test_pad_schedule_contract():
         pad_schedule(s, w - 1, r, oob_row=99)
     with pytest.raises(ValueError):
         pad_schedule(s, w + 1, r, oob_row=99)
-
-
-# ---------------------------------------------------------------------------
-# Block autotuning
-# ---------------------------------------------------------------------------
-
-
-def _ctx(backend="pallas", m=16, k=16, n=16, seed=8):
-    be = get_backend(backend)
-    rng = np.random.default_rng(seed)
-    bm, bk, bn = BS
-    occ_a = rng.random((m // bm, k // bk)) < 0.6
-    occ_b = rng.random((k // bk, n // bn)) < 0.6
-    occ_a[0, 0] = occ_b[0, 0] = True
-    shape = LayerShape(m, k, n, float(occ_a.mean()), float(occ_b.mean()),
-                       block=BS)
-    return SelectionContext(
-        shape=shape, block_shape=BS, occ_a=occ_a, occ_b=occ_b,
-        fingerprint=f"stream-test:{m}x{k}x{n}:{seed}", backend=be,
-        spec=TPUSpec(), allowed=allowed_dataflows(be, BS))
-
-
-def test_autotune_sweeps_backend_knobs():
-    from repro.backends.pallas import PallasBackend
-
-    # dedicated instance: the sweep applies winning knob values to the
-    # backend, which must not leak into the registered global instance
-    be = PallasBackend()
-    be.name = "pallas-knob-test"
-    ctx = _ctx(backend=be)
-    pol = AutotunePolicy(reps=1)
-    choice = pol.select(ctx)
-    assert choice in DATAFLOWS
-    assert pol.measurements == 1
-    # the sweep covered the knob cross product and applied the winner
-    assert be.dense_threshold in be.tuning_knobs()["dense_threshold"]
-    # cache hit re-applies without measuring
-    assert pol.select(ctx) == choice and pol.measurements == 1
-
-
-def test_select_block_sweeps_and_persists(tmp_path):
-    db_path = str(tmp_path / "tune.sqlite")
-    cands = ((8, 8, 8), (16, 16, 16))
-    p1 = AutotunePolicy(reps=1, db=db_path)
-    best = p1.select_block(_ctx(), cands)
-    assert best in cands and p1.measurements == 1
-    # in-memory LRU hit
-    assert p1.select_block(_ctx(), cands) == best and p1.measurements == 1
-    # a second process starts hot from the shared DB — no sweep
-    p2 = AutotunePolicy(reps=1, db=db_path)
-    assert p2.select_block(_ctx(), cands) == best
-    assert p2.measurements == 0 and p2.db_hits == 1
-    with pytest.raises(ValueError):
-        p1.select_block(_ctx(), ())
 
 
 # ---------------------------------------------------------------------------
